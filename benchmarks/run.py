@@ -22,6 +22,8 @@ import sys
 import time
 from pathlib import Path
 
+from repro.launch.compile_cache import use_compile_cache
+
 MODULES = [
     "table1_ops",
     "fig2_transfer_size",
@@ -54,6 +56,7 @@ def main() -> int:
                     help="modules whose rows() takes trace_dir= attach an "
                          "obs.Sampler and drop per-run time-series CSVs here")
     args = ap.parse_args()
+    use_compile_cache()
     only = [s.strip() for s in args.only.split(",") if s.strip()]
 
     all_rows = []

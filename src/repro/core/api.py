@@ -77,7 +77,7 @@ class dto:
             out = d.wait(d.fill_async(jnp.asarray([word], jnp.uint32), nbytes // 4))
             from repro.kernels.ops import from_words
 
-            return from_words(out.reshape(-1), nbytes // 4, x.shape, x.dtype)
+            return from_words(out, x.shape, x.dtype)
         return jnp.full_like(x, 0 if byte == 0 else byte)
 
     @staticmethod

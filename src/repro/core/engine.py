@@ -210,7 +210,7 @@ class StreamEngine:
         self.interpret = (
             self.config.interpret
             if self.config.interpret is not None
-            else jax.default_backend() != "tpu"
+            else ops._interpret_default()
         )
         self.model = self.config.model
         self._slots: Dict[str, List[_PESlot]] = {

@@ -18,7 +18,6 @@ from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.optim.gradients import compress_int8, decompress_int8
@@ -44,11 +43,11 @@ def ring_all_reduce(x: jax.Array, mesh: Mesh, axis: str = "data") -> jax.Array:
 
     other = [a for a in mesh.axis_names if a != axis]
     spec = P()  # replicated input/output w.r.t. this axis
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=P(*[None] * x.ndim),
         out_specs=P(*[None] * x.ndim),
-        check_rep=False,
+        check_vma=False,
     )(x)
 
 
@@ -71,11 +70,11 @@ def compressed_psum_tree(grads: Any, mesh: Mesh, axis: str, error_fb: Optional[A
             ssum = jax.lax.pmean(s_l, axis)
             return qsum, ssum
 
-        qs, ss = shard_map(
+        qs, ss = jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(*[None] * q.ndim), P()),
             out_specs=(P(*[None] * q.ndim), P()),
-            check_rep=False,
+            check_vma=False,
         )(q, scale)
         n = mesh.shape[axis]
         red = (qs.astype(jnp.float32) * ss / n).astype(g.dtype)

@@ -1,25 +1,46 @@
 """Memory Compare / Compare Pattern kernels (paper Table 1, "Compare").
 
-Each grid block emits (mismatch_count, first_diff_index_or_-1) for its tile;
-the ops layer reduces blocks to the global (equal?, first_diff) pair —
-matching DSA's completion-record semantics (status + first-diff offset).
+The grid walks the word grid in order and keeps one resident int32 block
+of the lowest differing word index seen at each (row, lane) position of a
+tile (``NO_DIFF`` where none).  The ops layer takes its minimum: the global
+first-diff index, or NO_DIFF for equal buffers, which matches DSA's
+completion-record semantics (status + first-diff offset).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.kernels.fill import pattern_spec, pattern_tile
+
 LANES = 128
+NO_DIFF = np.iinfo(np.int32).max
 
 
-def _compare_kernel(a_ref, b_ref, out_ref):
-    diff = a_ref[...] != b_ref[...]
-    n = jnp.sum(diff.astype(jnp.int32))
-    flat = diff.reshape(-1)
-    idx = jnp.argmax(flat).astype(jnp.int32)
-    out_ref[0, 0] = n
-    out_ref[0, 1] = jnp.where(n > 0, idx, -1)
+def fold_first_diff(first_ref, diff) -> None:
+    """Lower ``first_ref`` (the block-shaped running minimum, resident
+    across the sequential grid) with the word indices where ``diff``."""
+    i = pl.program_id(0)
+
+    @pl.when(i == 0)
+    def _init():
+        first_ref[...] = jnp.full(first_ref.shape, NO_DIFF, jnp.int32)
+
+    rows, lanes = diff.shape
+    row = jax.lax.broadcasted_iota(jnp.int32, diff.shape, 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, diff.shape, 1)
+    idx = (i * rows + row) * lanes + lane
+    first_ref[...] = jnp.minimum(first_ref[...], jnp.where(diff, idx, NO_DIFF))
+
+
+def first_diff_spec(block_rows: int) -> pl.BlockSpec:
+    return pl.BlockSpec((block_rows, LANES), lambda i: (0, 0))
+
+
+def _compare_kernel(a_ref, b_ref, first_ref):
+    fold_first_diff(first_ref, a_ref[...] != b_ref[...])
 
 
 def compare_words(
@@ -29,38 +50,29 @@ def compare_words(
     block_rows: int = 8,
     interpret: bool = False,
 ) -> jax.Array:
-    """Returns per-block [n_blocks, 2] i32: (mismatches, first_idx|-1)."""
+    """Returns the [block_rows, 128] i32 running minimum of differing word
+    indices (NO_DIFF where none)."""
     rows = a.shape[0]
     assert a.shape == b.shape and rows % block_rows == 0
-    n_blocks = rows // block_rows
+    spec = pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))
     return pl.pallas_call(
         _compare_kernel,
-        grid=(n_blocks,),
-        in_specs=[
-            pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 2), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_blocks, 2), jnp.int32),
+        grid=(rows // block_rows,),
+        in_specs=[spec, spec],
+        out_specs=first_diff_spec(block_rows),
+        out_shape=jax.ShapeDtypeStruct((block_rows, LANES), jnp.int32),
         interpret=interpret,
     )(a, b)
 
 
-def _compare_pattern_kernel(a_ref, pat_ref, out_ref):
-    rows, lanes = a_ref.shape
-    p = pat_ref.shape[-1]
-    lane_idx = jax.lax.broadcasted_iota(jnp.int32, (rows, lanes), 1) % p
-    expect = jnp.take(pat_ref[0], lane_idx, axis=0)
-    diff = a_ref[...] != expect
-    n = jnp.sum(diff.astype(jnp.int32))
-    idx = jnp.argmax(diff.reshape(-1)).astype(jnp.int32)
-    out_ref[0, 0] = n
-    out_ref[0, 1] = jnp.where(n > 0, idx, -1)
+def _compare_pattern_kernel(a_ref, pat_ref, first_ref):
+    a = jax.lax.bitcast_convert_type(a_ref[...], jnp.int32)
+    fold_first_diff(first_ref, a != pattern_tile(pat_ref, a.shape))
 
 
 def compare_pattern_words(
-    a: jax.Array,
-    pattern: jax.Array,  # [p] uint32
+    a: jax.Array,  # [rows, 128] uint32
+    pattern: jax.Array,  # [p] int32
     *,
     block_rows: int = 8,
     interpret: bool = False,
@@ -68,15 +80,11 @@ def compare_pattern_words(
     rows = a.shape[0]
     p = pattern.shape[0]
     assert rows % block_rows == 0 and LANES % p == 0
-    n_blocks = rows // block_rows
     return pl.pallas_call(
         _compare_pattern_kernel,
-        grid=(n_blocks,),
-        in_specs=[
-            pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1, p), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 2), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_blocks, 2), jnp.int32),
+        grid=(rows // block_rows,),
+        in_specs=[pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)), pattern_spec()],
+        out_specs=first_diff_spec(block_rows),
+        out_shape=jax.ShapeDtypeStruct((block_rows, LANES), jnp.int32),
         interpret=interpret,
-    )(a, pattern.reshape(1, p))
+    )(a, pattern)

@@ -1,11 +1,11 @@
 """Create Delta Record kernel (paper Table 1, "Compare").
 
 The DSA emits (offset, 8-byte data) pairs for differing granules.  TPU
-adaptation: the kernel computes the vectorized word-granule diff mask and
-per-block mismatch counts (the streaming part); the ops layer compacts the
-mask into the fixed-capacity record with ``jnp.nonzero(size=cap)`` — the
-record capacity mirrors DSA's max delta record size, with the same overflow
-status semantics in the completion record.
+adaptation: the kernel computes the vectorized word-granule diff mask (the
+streaming part); the ops layer compacts the mask into the fixed-capacity
+record with ``jnp.nonzero(size=cap)`` — the record capacity mirrors DSA's
+max delta record size, with the same overflow status semantics in the
+completion record.
 """
 from __future__ import annotations
 
@@ -16,10 +16,8 @@ from jax.experimental import pallas as pl
 LANES = 128
 
 
-def _delta_mask_kernel(src_ref, ref_ref, mask_ref, count_ref):
-    diff = src_ref[...] != ref_ref[...]
-    mask_ref[...] = diff
-    count_ref[0, 0] = jnp.sum(diff.astype(jnp.int32))
+def _delta_mask_kernel(src_ref, ref_ref, mask_ref):
+    mask_ref[...] = (src_ref[...] != ref_ref[...]).astype(jnp.int32)
 
 
 def delta_mask_words(
@@ -28,20 +26,16 @@ def delta_mask_words(
     *,
     block_rows: int = 8,
     interpret: bool = False,
-):
-    """Returns (mask [rows,128] bool, per-block counts [n_blocks,1] i32)."""
+) -> jax.Array:
+    """Returns the [rows, 128] int32 mask: 1 where the words differ."""
     rows = src.shape[0]
     assert src.shape == ref.shape and rows % block_rows == 0
-    n_blocks = rows // block_rows
     spec = pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))
     return pl.pallas_call(
         _delta_mask_kernel,
-        grid=(n_blocks,),
+        grid=(rows // block_rows,),
         in_specs=[spec, spec],
-        out_specs=[spec, pl.BlockSpec((1, 1), lambda i: (i, 0))],
-        out_shape=[
-            jax.ShapeDtypeStruct(src.shape, jnp.bool_),
-            jax.ShapeDtypeStruct((n_blocks, 1), jnp.int32),
-        ],
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct(src.shape, jnp.int32),
         interpret=interpret,
     )(src, ref)

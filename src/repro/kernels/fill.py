@@ -10,29 +10,41 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 
 
+def pattern_spec() -> pl.BlockSpec:
+    """The [p] int32 pattern words live whole in SMEM (scalar reads)."""
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def pattern_tile(pat_ref, shape) -> jax.Array:
+    """The pattern laid over a (rows, 128) int32 tile without a gather.
+    p divides the lane width, so lane l of every row holds pattern word
+    l % p whatever the tile's offset; a select chain picks it."""
+    p = pat_ref.shape[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1) & (p - 1)
+    tile = jnp.full(shape, pat_ref[0], jnp.int32)
+    for j in range(1, p):
+        tile = jnp.where(lane == j, pat_ref[j], tile)
+    return tile
+
+
 def _fill_kernel(pat_ref, dst_ref):
-    rows, lanes = dst_ref.shape
-    p = pat_ref.shape[-1]
-    pat = pat_ref[0]  # [p]
-    # lane l of row r holds word index (block_offset + r*lanes + l); the
-    # pattern index depends only on (global word index % p) — p divides LANES
-    # for p in (2, 4), so the tile pattern is position-independent.
-    lane_idx = jax.lax.broadcasted_iota(jnp.int32, (rows, lanes), 1) % p
-    dst_ref[...] = jnp.take(pat, lane_idx, axis=0)
+    dst_ref[...] = pattern_tile(pat_ref, dst_ref.shape)
 
 
 def fill_words(
     rows: int,
-    pattern: jax.Array,  # [p] uint32, p in (1, 2, 4)
+    pattern: jax.Array,  # [p] int32, p divides 128
     *,
     block_rows: int = 8,
     n_pe: int = 1,
     interpret: bool = False,
 ) -> jax.Array:
+    """Returns the filled [rows, 128] int32 word grid."""
     assert rows % (block_rows * n_pe) == 0
     p = pattern.shape[0]
     assert LANES % p == 0, "pattern must divide the lane width"
@@ -40,8 +52,8 @@ def fill_words(
     return pl.pallas_call(
         _fill_kernel,
         grid=(n_pe, blocks_per_pe),
-        in_specs=[pl.BlockSpec((1, p), lambda pe, j: (0, 0))],
+        in_specs=[pattern_spec()],
         out_specs=pl.BlockSpec((block_rows, LANES), lambda pe, j, bpp=blocks_per_pe: (pe * bpp + j, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
         interpret=interpret,
-    )(pattern.reshape(1, p))
+    )(pattern)

@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.ops import _interpret_default
+
 NEG_INF = -1e30
 
 
@@ -84,7 +86,7 @@ def flash_attention(
 ) -> jax.Array:
     """Drop-in replacement for models.layers.attention (fwd)."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = _interpret_default()
     B, Sq, H, hd = q.shape
     _, Skv, KV, _ = k.shape
     G = H // KV

@@ -1,19 +1,15 @@
-"""Fused streaming kernels — the hot-path op pairs in ONE Pallas launch.
+"""Fused fill + verify — the hot-path op pair in ONE Pallas launch.
 
 The paper's per-descriptor cost model (Fig. 2/3) says small-op throughput is
 launch-bound: two descriptors that always travel together pay two launch
-overheads and stream the data twice.  These kernels fuse the two pairs the
-repo actually submits back-to-back:
+overheads and stream the data twice.  Two pairs are fused:
 
-  copy_crc     memcpy + CRC32: each grid step copies its tile to the
-               destination AND folds it into the chunk CRC states — one
-               launch, one read pass (checkpointing copies a leaf out and
-               checksums it; unfused that is a 1.0x copy plus a 0.5x CRC
-               read across two launches).
-  fill_verify  fill + compare_pattern: each grid step writes the pattern
-               tile and immediately reads it back for the per-block
-               (mismatches, first_idx) verification record — one launch
-               instead of a 0.5x fill plus a 0.5x compare.
+  copy_crc     memcpy + CRC32: the CRC kernel streams each tile to a second
+               output as it folds it (``crc32.crc_streams(copy=True)``).
+  fill_verify  fill + compare_pattern (here): each grid step writes the
+               pattern tile and immediately reads it back into the running
+               first-mismatch record — one launch instead of a 0.5x fill
+               plus a 0.5x compare.
 
 Both are bit-exact against the unfused pairs (tests/test_hotpath.py sweeps
 sizes and payloads); the ops layer wraps them with the same word-grid
@@ -25,107 +21,43 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.crc32 import INIT, _crc_step
+from repro.kernels.compare import first_diff_spec, fold_first_diff
+from repro.kernels.fill import pattern_spec, pattern_tile
 
 LANES = 128
 
 
-# ------------------------------------------------------------------ copy+crc
-def _copy_crc_kernel(tabs_ref, data_ref, state_ref, dst_ref):
-    """Grid step i: copy ``wb`` words of every chunk to the destination and
-    advance the per-chunk CRC states over the same tile (states carry
-    across sequential grid steps in the output ref, as in _crc_kernel)."""
-
-    @pl.when(pl.program_id(0) == 0)
-    def _init():
-        state_ref[...] = jnp.full(state_ref.shape, jnp.uint32(INIT), jnp.uint32)
-
-    tabs = tabs_ref[...]
-    blk = data_ref[...]  # [C, wb]
-    dst_ref[...] = blk  # the copy: same tile, one read feeds both outputs
-    wb = blk.shape[1]
-    st = state_ref[...][:, 0]
-
-    def body(i, st):
-        return _crc_step(st, blk[:, i], tabs)
-
-    st = jax.lax.fori_loop(0, wb, body, st)
-    state_ref[...] = st[:, None]
-
-
-def copy_crc_words(
-    data: jax.Array,  # [C, W] uint32 — C chunks of W words
-    tables: jax.Array,  # [4, 256] uint32
-    *,
-    words_per_step: int = 512,
-    interpret: bool = False,
-):
-    """Returns (per-chunk CRC states [C] u32 post final-xor, copy [C, W])."""
-    C, W = data.shape
-    wb = min(words_per_step, W)
-    while W % wb != 0:
-        wb -= 1
-    n_steps = W // wb
-    states, dst = pl.pallas_call(
-        _copy_crc_kernel,
-        grid=(n_steps,),
-        in_specs=[
-            pl.BlockSpec((4, 256), lambda i: (0, 0)),
-            pl.BlockSpec((C, wb), lambda i: (0, i)),
-        ],
-        out_specs=[
-            pl.BlockSpec((C, 1), lambda i: (0, 0)),
-            pl.BlockSpec((C, wb), lambda i: (0, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((C, 1), jnp.uint32),
-            jax.ShapeDtypeStruct((C, W), jnp.uint32),
-        ],
-        interpret=interpret,
-    )(tables, data)
-    return states[:, 0] ^ jnp.uint32(INIT), dst
-
-
-# ------------------------------------------------------------------ fill+verify
-def _fill_verify_kernel(pat_ref, dst_ref, chk_ref):
-    """Write the pattern tile, then read the destination back and emit the
-    per-block (mismatch_count, first_idx|-1) verification record — the
-    compare_pattern contract computed from the just-written memory."""
-    rows, lanes = dst_ref.shape
-    p = pat_ref.shape[-1]
-    lane_idx = jax.lax.broadcasted_iota(jnp.int32, (rows, lanes), 1) % p
-    expect = jnp.take(pat_ref[0], lane_idx, axis=0)
+def _fill_verify_kernel(pat_ref, dst_ref, first_ref):
+    """Write the pattern tile, then read the destination back into the
+    compare_pattern record — computed from the just-written memory."""
+    expect = pattern_tile(pat_ref, dst_ref.shape)
     dst_ref[...] = expect
-    diff = dst_ref[...] != expect  # readback verify of the written tile
-    n = jnp.sum(diff.astype(jnp.int32))
-    idx = jnp.argmax(diff.reshape(-1)).astype(jnp.int32)
-    chk_ref[0, 0] = n
-    chk_ref[0, 1] = jnp.where(n > 0, idx, -1)
+    fold_first_diff(first_ref, dst_ref[...] != expect)
 
 
 def fill_verify_words(
     rows: int,
-    pattern: jax.Array,  # [p] uint32, p in (1, 2, 4)
+    pattern: jax.Array,  # [p] int32, p divides 128
     *,
     block_rows: int = 8,
     interpret: bool = False,
 ):
-    """Returns (filled [rows, 128] u32, per-block [n_blocks, 2] i32)."""
+    """Returns (filled [rows, 128] i32, running first-mismatch block
+    [block_rows, 128] i32 as compare.compare_words)."""
     assert rows % block_rows == 0
     p = pattern.shape[0]
     assert LANES % p == 0, "pattern must divide the lane width"
-    n_blocks = rows // block_rows
     return pl.pallas_call(
         _fill_verify_kernel,
-        grid=(n_blocks,),
-        in_specs=[pl.BlockSpec((1, p), lambda i: (0, 0))],
+        grid=(rows // block_rows,),
+        in_specs=[pattern_spec()],
         out_specs=[
             pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1, 2), lambda i: (i, 0)),
+            first_diff_spec(block_rows),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((rows, LANES), jnp.uint32),
-            jax.ShapeDtypeStruct((n_blocks, 2), jnp.int32),
+            jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
+            jax.ShapeDtypeStruct((block_rows, LANES), jnp.int32),
         ],
         interpret=interpret,
-    )(pattern.reshape(1, p))
+    )(pattern)

@@ -16,32 +16,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# --------------------------------------------------------------------------- CRC32 tables
+# --------------------------------------------------------------------------- CRC32 combine
 _POLY = 0xEDB88320  # reflected IEEE
 
 
-def _make_crc_table() -> np.ndarray:
-    tab = np.zeros(256, dtype=np.uint64)
-    for i in range(256):
-        c = np.uint64(i)
-        for _ in range(8):
-            c = (c >> np.uint64(1)) ^ (np.uint64(_POLY) * (c & np.uint64(1)))
-        tab[i] = c
-    return tab.astype(np.uint32)
-
-
-def make_crc_tables(n: int = 4) -> np.ndarray:
-    """Slice-by-n tables [n, 256] uint32 (T0 = classic byte table)."""
-    t0 = _make_crc_table()
-    tabs = [t0]
-    for _ in range(n - 1):
-        prev = tabs[-1]
-        nxt = (t0[prev & 0xFF] ^ (prev >> np.uint32(8))).astype(np.uint32)
-        tabs.append(nxt)
-    return np.stack(tabs)  # [n, 256]
-
-
-# GF(2) combine machinery (zlib crc32_combine) -------------------------------
 def _gf2_matrix_times(mat: np.ndarray, vec: int) -> int:
     s = 0
     i = 0
@@ -60,47 +38,23 @@ def _gf2_matrix_square(mat: np.ndarray) -> np.ndarray:
 def crc32_shift_matrix(length_bytes: int) -> np.ndarray:
     """Matrix advancing a CRC state over ``length_bytes`` zero bytes: [32] u32
     columns (column i = image of bit i)."""
-    # operator for one zero BIT
-    odd = np.zeros(32, dtype=np.uint64)
-    odd[0] = np.uint64(_POLY)
-    for i in range(1, 32):
-        odd[i] = np.uint64(1) << np.uint64(i - 1)
-    even = _gf2_matrix_square(odd)  # 2 bits
-    odd = _gf2_matrix_square(even)  # 4 bits
-    # now square/apply over len*8 bits
-    mat_pairs = [even, odd]
-    n = length_bytes
-    if n == 0:
-        ident = np.array([1 << i for i in range(32)], dtype=np.uint64)
-        return ident.astype(np.uint32)
-    result = None
-    cur = 0
-    # first application: even = 4-bit?? — follow zlib: loop applying squares of 4-zero-BYTE ops
-    # zlib: even starts as "2 zero bytes" after 3 squarings of the 1-bit op.
-    # Rebuild cleanly: op1 = 1 zero byte = (1-bit op)^8
+    # one zero BIT, squared 3x -> one zero BYTE
     op = np.zeros(32, dtype=np.uint64)
     op[0] = np.uint64(_POLY)
     for i in range(1, 32):
         op[i] = np.uint64(1) << np.uint64(i - 1)
-    for _ in range(3):  # ^8 = square 3x
+    for _ in range(3):
         op = _gf2_matrix_square(op)
     # binary exponentiation over bytes
-    ident = np.array([1 << i for i in range(32)], dtype=np.uint64)
-    result = ident.copy()
+    result = np.array([1 << i for i in range(32)], dtype=np.uint64)
     base = op
+    n = length_bytes
     while n:
         if n & 1:
             result = np.array([_gf2_matrix_times(base, int(r)) for r in result], dtype=np.uint64)
         base = _gf2_matrix_square(base)
         n >>= 1
     return result.astype(np.uint32)
-
-
-def crc32_combine(crc1: int, crc2: int, len2: int) -> int:
-    if len2 == 0:
-        return crc1
-    mat = crc32_shift_matrix(len2)
-    return _gf2_matrix_times(mat.astype(np.uint64), crc1) ^ crc2
 
 
 # --------------------------------------------------------------------------- oracles

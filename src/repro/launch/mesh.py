@@ -11,10 +11,8 @@ import jax
 
 
 def axis_types_kw(n_axes: int) -> dict:
-    """axis_types=(Auto, ...) where the installed jax supports it (>=0.5);
-    older versions default every axis to Auto already."""
-    at = getattr(jax.sharding, "AxisType", None)
-    return {"axis_types": (at.Auto,) * n_axes} if at is not None else {}
+    """axis_types=(Auto, ...): every mesh axis under GSPMD auto-sharding."""
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

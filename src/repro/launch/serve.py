@@ -1,7 +1,10 @@
 """Serving driver: continuous batching with the Vhost-style 3-stage pipeline.
 
     PYTHONPATH=src python -m repro.launch.serve --arch tinyllama-1.1b \
-        --requests 16 --slots 4 --max-new 8
+        --requests 16 --slots 4 --max-new 8 [--no-reduced]
+
+``--no-reduced`` serves the architecture at its published widths; the
+default is the reduced preset.
 """
 from __future__ import annotations
 
@@ -13,13 +16,16 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core import make_device
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.api import build_model
 from repro.serving.pipeline import Request, VhostStyleServer
 
 
-def main():
+def parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b")
+    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--no-reduced", dest="reduced", action="store_false")
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
@@ -29,9 +35,15 @@ def main():
     ap.add_argument("--policy", default="least_loaded",
                     choices=["round_robin", "least_loaded", "sticky"])
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    return ap
 
-    cfg = get_config(args.arch).reduced()
+
+def serve(args) -> VhostStyleServer:
+    """Serve ``args.requests`` random prompts to completion; returns the
+    drained server (its params, metrics and device)."""
+    cfg = get_config(args.arch)
+    if args.reduced:
+        cfg = cfg.reduced()
     model = build_model(cfg, remat=False)
     params = model.init(jax.random.key(args.seed))
     server = VhostStyleServer(
@@ -52,11 +64,19 @@ def main():
     m = server.metrics
     ps = server.device.policy_stats
     placed = ", ".join(f"{k}={v}" for k, v in sorted(ps["decisions"].items()))
-    print(f"served {m['completed']}/{args.requests} requests in {steps} pipeline steps, "
-          f"{dt:.2f}s; decoded {m['decoded_tokens']} tokens "
-          f"({m['decoded_tokens']/dt:.1f} tok/s); copy bursts {m['copy_bursts']}; "
+    print(f"served {m['completed']}/{args.requests} requests in {steps} pipeline steps "
+          f"(cold wall {dt:.2f}s incl. compiles); decoded {m['decoded_tokens']} tokens; "
+          f"copy bursts {m['copy_bursts']}; "
           f"policy {ps['policy']} placements [{placed}]")
-    assert m["completed"] == args.requests
+    if m["completed"] != args.requests:
+        raise RuntimeError(f"served {m['completed']} of {args.requests} requests")
+    return server
+
+
+def main(argv=None):
+    args = parser().parse_args(argv)
+    use_compile_cache()
+    serve(args)
 
 
 if __name__ == "__main__":

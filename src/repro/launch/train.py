@@ -24,6 +24,7 @@ from repro.data.pipeline import Prefetcher, SyntheticLMDataset
 from repro.distributed.annotate import use_rules
 from repro.distributed.fault import Heartbeat, StragglerDetector, run_with_restarts
 from repro.distributed.sharding import rules_for_mesh
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import make_train_step
 from repro.models.api import build_model
@@ -119,6 +120,7 @@ def main():
                     choices=["round_robin", "least_loaded", "sticky"])
     ap.add_argument("--crc-impl", default="zlib", choices=["zlib", "kernel"])
     args = ap.parse_args()
+    use_compile_cache()
     train(args)
 
 

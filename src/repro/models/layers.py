@@ -111,7 +111,6 @@ def gated_mlp(x: jax.Array, p: dict, act: str = "silu", tp_comm: str = "auto") -
 def _tp_block_manual(x, p, fn):
     """Megatron-style column+row parallel MLP with bf16 wire; returns None
     when the mesh/rules context is absent or the FF dim isn't model-sharded."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed.annotate import _current
@@ -130,17 +129,16 @@ def _tp_block_manual(x, p, fn):
         part = (h @ w2_l).astype(x_l.dtype)  # cast BEFORE the wire
         return jax.lax.psum(part, w1_spec[1])
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(x_spec, w1_spec, w1_spec, P(w1_spec[1], None)),
-        out_specs=x_spec, check_rep=False,
+        out_specs=x_spec, check_vma=False,
     )(x, p["w1"], p["w3"], p["w2"])
 
 
 def row_parallel_out(o_flat: jax.Array, wo: jax.Array, tp_comm: str = "auto") -> jax.Array:
     """Attention output projection [B,S,H*hd] @ [H*hd,D], row-parallel with
     bf16-wire psum when tp_comm="manual_bf16" (same rationale as gated_mlp)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed.annotate import _current
@@ -161,8 +159,8 @@ def row_parallel_out(o_flat: jax.Array, wo: jax.Array, tp_comm: str = "auto") ->
         part = (o_l @ w_l).astype(o_l.dtype)
         return jax.lax.psum(part, wo_spec[0])
 
-    return shard_map(local, mesh=mesh, in_specs=(o_spec, wo_spec),
-                     out_specs=out_spec, check_rep=False)(o_flat, wo)
+    return jax.shard_map(local, mesh=mesh, in_specs=(o_spec, wo_spec),
+                         out_specs=out_spec, check_vma=False)(o_flat, wo)
 
 
 # --------------------------------------------------------------------------- attention
@@ -317,7 +315,6 @@ def _flash_call(q, k, v, causal, window, n_meta):
     replicated whenever KV % tp != 0, so every q-head shard has its K/V).
     Falls back to a direct call when dims don't divide.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed.annotate import _current
@@ -341,9 +338,9 @@ def _flash_call(q, k, v, causal, window, n_meta):
     kv_shard = _size(kv_spec[2])
     if kv_shard not in (1, h_shard):
         return kernel(q, k, v)
-    return shard_map(
+    return jax.shard_map(
         kernel, mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec),
-        out_specs=q_spec, check_rep=False,
+        out_specs=q_spec, check_vma=False,
     )(q, k, v)
 
 

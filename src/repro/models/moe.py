@@ -127,7 +127,6 @@ def _moe_a2a(xt, weights, idx, p, cfg: MoEConfig, act, mesh) -> jax.Array:
     never with the full [T, D] batch (dense-dispatch baseline) and never
     with expert weights (FSDP gathers)."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     from repro.distributed.annotate import _current
 
@@ -202,12 +201,12 @@ def _moe_a2a(xt, weights, idx, p, cfg: MoEConfig, act, mesh) -> jax.Array:
         return jax.lax.psum(y_partial, "model")
 
     flat_spec = P(tok_spec[0], None)  # routing weights / indices [T, k]
-    y = shard_map(
+    y = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(tok_spec, flat_spec, flat_spec, w1_spec, w1_spec, w2_spec),
         out_specs=tok_spec,
-        check_rep=False,
+        check_vma=False,
     )(xt, weights, idx, p["w1"], p["w3"], p["w2"])
     return y
 

@@ -37,7 +37,6 @@ class PoolStats:
     batch_copies: int = 0
     pages_moved: int = 0
     cross_node_swaps: int = 0  # swaps whose src and dst homes differ
-    copy_fallbacks: int = 0  # engine path failed -> sync kops.batch_copy
 
 
 class PagedKVPool:
@@ -184,22 +183,20 @@ class PagedKVPool:
 
     # ------------------------------------------------------------------ tier moves (batch descriptors)
     def _batch_copy(self, src_pool, dst_pool, src_idx, dst_idx, dst_node=None):
-        """One per-node batch descriptor through the engine, falling back to
-        the synchronous kernel when the offload path fails (QueueFull after
-        backoff, engine error): a saturated fabric degrades to a slow swap,
-        never a lost one.  Registered pools let the descriptor derive its
-        src/dst nodes; ``dst_node`` homes the INTERMEDIATE pools a chained
-        multi-node swap mints (functional updates return fresh, unregistered
-        arrays), so every per-node batch keeps its cross-node link charge."""
-        if self.device is not None:
-            try:
-                return self.device.batch_copy_async(
-                    src_pool, dst_pool, src_idx, dst_idx, producer="kv-pool",
-                    node=dst_node,
-                ).result()
-            except Exception:  # noqa: BLE001  # dsalint: disable=DSA104 — counted fallback to the sync copy path
-                self.stats.copy_fallbacks += 1
-        return kops.batch_copy(src_pool, dst_pool, src_idx, dst_idx)
+        """One per-node batch descriptor through the engine, or the kernel
+        directly when the pool has no device.  An engine failure (QueueFull
+        after backoff, kernel error) propagates: the swap fails and its
+        caller restores the free lists.  Registered pools let the
+        descriptor derive its src/dst nodes; ``dst_node`` homes the
+        INTERMEDIATE pools a chained multi-node swap mints (functional
+        updates return fresh, unregistered arrays), so every per-node batch
+        keeps its cross-node link charge."""
+        if self.device is None:
+            return kops.batch_copy(src_pool, dst_pool, src_idx, dst_idx)
+        return self.device.batch_copy_async(
+            src_pool, dst_pool, src_idx, dst_idx, producer="kv-pool",
+            node=dst_node,
+        ).result()
 
     def swap_out(self, seq_id: int) -> bool:
         """Device -> host: one batch descriptor PER SOURCE NODE.  Free-list

@@ -225,8 +225,10 @@ class VhostStyleServer:
         if futs:
             self.device.wait_any(futs[:1] if block else futs,
                                  timeout=None if block else 0)
-        for _, payload in self.reorder.pop_completed():
-            slot, req = payload
+        for _, (slot, req, fut) in self.reorder.pop_completed():
+            if fut.status != Status.SUCCESS:
+                raise RuntimeError(f"admission copy for request {req.req_id} "
+                                   f"failed: {fut.error}")
             self._admit_now(slot, req)
 
     def _admit_now(self, slot: int, req: Request):
@@ -365,7 +367,7 @@ class VhostStyleServer:
                     continue
                 self.queue.appendleft(req)
                 break
-            self.reorder.push(self._tag, fut, (slot, req))
+            self.reorder.push(self._tag, fut, (slot, req, fut))
             self._tag += 1
             self.metrics["copy_bursts"] += 1
 

@@ -1,6 +1,6 @@
 """Submit/complete hot path: fused submission (submit_many / SubmitRing),
 kick() slot reuse, fused Pallas pairs (copy_crc / fill_verify), the DSA106
-unbatched-submit-loop lint, and the bounded CRC shift-matrix cache."""
+unbatched-submit-loop lint, and the CRC operator tables."""
 import zlib
 
 import jax.numpy as jnp
@@ -296,15 +296,32 @@ def test_dsa106_suppression():
     assert [v for v in lint_source(src) if v.code == "DSA106"] == []
 
 
-# --------------------------------------------------------------------------- shift cache bound
-def test_crc_shift_cache_bounded():
-    from repro.kernels.ops import _SHIFT_CACHE, _SHIFT_CACHE_MAX, _shift_mat
+# --------------------------------------------------------------------------- CRC operator tables
+@pytest.mark.parametrize("nbytes", [0, 1, 3, 4, 64, 1518, 4096, 1 << 20])
+def test_crc_zeros_crc_matches_zlib(nbytes):
+    from repro.kernels.crc32 import zeros_crc
 
-    _SHIFT_CACHE.clear()
-    for nbytes in range(4, 4 + 4 * (_SHIFT_CACHE_MAX + 40), 4):
-        _shift_mat(nbytes)
-    assert len(_SHIFT_CACHE) == _SHIFT_CACHE_MAX
-    # LRU: the most recent keys survive, the oldest were evicted
-    last = 4 + 4 * (_SHIFT_CACHE_MAX + 39)
-    assert last in _SHIFT_CACHE
-    assert 4 not in _SHIFT_CACHE
+    assert zeros_crc(nbytes) == zlib.crc32(bytes(nbytes)) & 0xFFFFFFFF
+
+
+def test_crc_advance_columns_are_zero_word_feeds():
+    """Row m of the operator table is the register after m zero words:
+    A_1 matches zlib continuing over 4 zero bytes, and A_{m+n} = A_m A_n."""
+    from repro.kernels.crc32 import advance_columns
+
+    cols = advance_columns()
+    for b in (0, 7, 31):
+        reg = 1 << b
+        # zlib's running value is the register xor 0xFFFFFFFF on both ends
+        want = zlib.crc32(bytes(4), reg ^ 0xFFFFFFFF) ^ 0xFFFFFFFF
+        assert int(cols[1][b]) == want
+
+    def apply(m, v):
+        out = 0
+        for b in range(32):
+            if (v >> b) & 1:
+                out ^= int(cols[m][b])
+        return out
+
+    for b in range(32):
+        assert apply(1000, int(cols[24][b])) == int(cols[1024][b])

@@ -43,7 +43,7 @@ def test_fill_matches_ref(n_words, plen):
     assert (np.asarray(out) == np.asarray(want)).all()
 
 
-@pytest.mark.parametrize("n", [256, 1000, 4096])
+@pytest.mark.parametrize("n", [256, 1000, 4096, 100_000])
 def test_compare_finds_first_diff(rng, n):
     a = jnp.asarray(rng.integers(0, 2**31, n), jnp.uint32)
     eq, idx = ops.compare(a, a)
@@ -144,3 +144,56 @@ def test_dif_roundtrip_and_detection(rng):
     # update recomputes a valid frame after mutation
     fixed = dif.dif_update(corrupted)
     assert bool(np.asarray(dif.dif_check(fixed)).all())
+
+
+@pytest.mark.parametrize("nbytes", [1, 3, 64, 1518, 4099])
+def test_byte_granular_ops(rng, nbytes):
+    """Byte lengths that are not word multiples (a 1518 B frame): copies
+    are exact, CRCs match zlib, and compare reports the first differing
+    word."""
+    x = jnp.asarray(rng.integers(0, 256, nbytes), jnp.uint8)
+    assert (np.asarray(ops.memcpy(x)) == np.asarray(x)).all()
+    d1, d2 = ops.dualcast(x)
+    assert (np.asarray(d1) == np.asarray(x)).all() and (np.asarray(d2) == np.asarray(x)).all()
+    want = zlib.crc32(np.asarray(x).tobytes()) & 0xFFFFFFFF
+    assert int(ops.crc32(x)) == want
+    copy, crc = ops.copy_crc(x)
+    assert copy.dtype == x.dtype and (np.asarray(copy) == np.asarray(x)).all()
+    assert int(crc) == want
+    pos = nbytes - 1
+    eq, idx = ops.compare(x, x.at[pos].add(1))
+    assert not bool(eq) and int(idx) == pos // 4
+    eq, idx = ops.compare(x, x)
+    assert bool(eq) and int(idx) == -1
+
+
+def test_delta_apply_multi_block(rng):
+    """A record spanning several grid blocks, in any order, with -1 padding
+    interleaved: each entry lands in its own block; a later duplicate wins."""
+    n = 150_000
+    base = rng.integers(0, 2**32, n, dtype=np.uint32)
+    off = rng.choice(n, 300, replace=False).astype(np.int32)
+    off[::7] = -1
+    off[-1] = off[0] = 70_001
+    data = rng.integers(0, 2**32, off.shape[0], dtype=np.uint32)
+    want = base.copy()
+    for o, d in zip(off, data):
+        if o >= 0:
+            want[o] = d
+    got = ops.delta_apply(jnp.asarray(base), jnp.asarray(off), jnp.asarray(data))
+    assert (np.asarray(got) == want).all()
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.uint16, jnp.int8])
+def test_narrow_dtypes_any_length(rng, dtype):
+    """2- and 1-byte items at lengths that end mid-word: the word view
+    round-trips (memcpy, copy_crc) and the CRC covers exactly their bytes."""
+    itemsize = jnp.dtype(dtype).itemsize
+    for n in (1, 3, 513, 4097):
+        a = rng.integers(0, 256, n * itemsize, dtype=np.uint8).view(jnp.dtype(dtype))
+        x = jnp.asarray(a)
+        want = zlib.crc32(a.tobytes()) & 0xFFFFFFFF
+        copy, crc = ops.copy_crc(x)
+        assert np.asarray(ops.memcpy(x)).tobytes() == a.tobytes()
+        assert copy.dtype == x.dtype and np.asarray(copy).tobytes() == a.tobytes()
+        assert int(crc) == want and int(ops.crc32(x)) == want

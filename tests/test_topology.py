@@ -266,6 +266,9 @@ def test_server_rejects_device_and_topology():
 
 
 def test_kv_pool_engine_failure_falls_back_to_sync():
+    """An engine that refuses the swap (QueueFull after backoff) fails it:
+    the error surfaces from swap_out, and the page table and free lists
+    are exactly as before — no silent synchronous re-copy."""
     class BoomDevice:
         topology = Topology.symmetric(2)
 
@@ -280,9 +283,15 @@ def test_kv_pool_engine_failure_falls_back_to_sync():
     assert pool.alloc(1, 2)
     pool.write_page(1, 0, jnp.ones((4, 8)))
     before = np.asarray(pool.read_pages(1))
-    assert pool.swap_out(1)  # engine path failed -> sync kops, swap still lands
-    assert pool.stats.copy_fallbacks == 1
-    assert pool.swap_in(1)
+    entries_before = list(pool.page_table[1])
+    free_dev_before = pool.free_device_pages()
+    free_host_before = list(pool._free_host)
+    with pytest.raises(QueueFull):
+        pool.swap_out(1)
+    assert pool.page_table[1] == entries_before
+    assert pool.free_device_pages() == free_dev_before
+    assert pool._free_host == free_host_before
+    assert pool.stats.swaps_out == 0
     assert (np.asarray(pool.read_pages(1)) == before).all()
 
 
