@@ -27,7 +27,6 @@ class OpCounter:
     count: int = 0
     bytes: int = 0
     modeled_us: float = 0.0
-    wall_us: float = 0.0
 
 
 def size_bucket(nbytes: int) -> str:
@@ -72,7 +71,6 @@ class CounterStore:
         c.count += 1
         c.bytes += rec.bytes_processed
         c.modeled_us += rec.modeled_time_us
-        c.wall_us += rec.wall_time_us
         nt = self.node_traffic[node_id]
         if rec.link_hops > 0:
             nt["cross_ops"] += 1
@@ -86,7 +84,6 @@ class CounterStore:
             wc.count += 1
             wc.bytes += rec.bytes_processed
             wc.modeled_us += rec.modeled_time_us
-            wc.wall_us += rec.wall_time_us
 
     def drain_engine(self, engine) -> int:
         """Walk one engine's completion records, counting each resolved

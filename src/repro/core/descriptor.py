@@ -140,7 +140,6 @@ class CompletionRecord:
     result: Any = None  # op-specific payload (arrays / scalars)
     bytes_processed: int = 0
     modeled_time_us: float = 0.0  # perfmodel estimate on the target TPU
-    wall_time_us: float = 0.0  # measured host time (interpret mode)
     error: Optional[str] = None
     # WQ QoS attribution (paper Fig. 9 / Fig. 12): which WQ dispatched the
     # descriptor, how long it sat queued, and where completions were steered

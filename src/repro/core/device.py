@@ -482,6 +482,9 @@ class Device:
             from repro.obs.trace import make_tracer
 
             self.tracer = make_tracer(trace)
+        if self.tracer is not None:
+            self.tracer.attach()  # released by close()
+        self._closed = False
         # submit-time descriptor validation mode (repro.analysis.desclint):
         # strict raises the typed DescriptorError taxonomy, warn bumps the
         # desclint_warnings counter, off skips the checks
@@ -1131,6 +1134,13 @@ class Device:
             self._dispatch_done()  # callbacks fire outside the lock
             if done:
                 return
+
+    def close(self) -> None:
+        """Let go of the tracer, whose garbage-collection hook goes with
+        its last device.  Idempotent; the engines need no teardown."""
+        if self.tracer is not None and not self._closed:
+            self.tracer.close()
+        self._closed = True
 
 
 class SubmitRing:

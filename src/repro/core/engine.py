@@ -48,6 +48,20 @@ from repro.kernels import dif as dif_ops
 from repro.kernels import ops
 
 
+#: perfmodel read factor of each op (1.0 where absent): DUALCAST reads
+#: once and writes twice; FILL, CACHE_FLUSH and FILL_VERIFY only write (the
+#: fused fill+compare_pattern verifies the tile just written in-kernel, so
+#: the pair costs one fill, not fill + compare_pattern across two
+#: launches); the checks and CRC32 only read.  COPY_CRC, the fused
+#: memcpy+CRC32, reads once for both the copy and the checksum, against two
+#: launches and two read passes (memcpy 1.0 + crc32 0.5) unfused.
+_READ_FACTOR = {
+    OpType.DUALCAST: 1.5, OpType.FILL: 0.5, OpType.COMPARE_PATTERN: 0.5,
+    OpType.CRC32: 0.5, OpType.DIF_CHECK: 0.5, OpType.FILL_VERIFY: 0.5,
+    OpType.CACHE_FLUSH: 0.5,
+}
+
+
 def _ready(x) -> bool:
     try:
         return x.is_ready()
@@ -136,7 +150,6 @@ class _PESlot:
         self.record: Optional[CompletionRecord] = None
         self.work: Optional[concurrent.futures.Future] = None
         self.outputs: Any = None
-        self.t0: float = 0.0
 
     @property
     def busy(self) -> bool:
@@ -154,7 +167,6 @@ class _PESlot:
             except Exception as e:  # noqa: BLE001 — kernel dispatch failed
                 rec.status = Status.ERROR
                 rec.error = f"{type(e).__name__}: {e}"
-                rec.wall_time_us = (time.perf_counter() - self.t0) * 1e6
                 self.record = None
                 self.work = None
                 self.outputs = None
@@ -166,7 +178,6 @@ class _PESlot:
             self.work = None
         leaves = jax.tree.leaves(self.outputs)
         if all(_ready(x) for x in leaves):
-            self.record.wall_time_us = (time.perf_counter() - self.t0) * 1e6
             if self.record.status == Status.RUNNING:
                 self.record.status = Status.SUCCESS
             self.record = None
@@ -239,7 +250,7 @@ class StreamEngine:
         # fences), matching what a record-walking Telemetry counts.
         self.counters: Dict[str, float] = {
             "completed": 0, "errors": 0, "bytes": 0,
-            "modeled_us": 0.0, "wall_us": 0.0,
+            "modeled_us": 0.0,
             "local_ops": 0, "local_bytes": 0,
             "cross_ops": 0, "cross_bytes": 0, "link_bytes": 0,
             # submission-side counters: every accepted descriptor bumps
@@ -285,7 +296,6 @@ class StreamEngine:
                 c["errors"] += 1
             c["bytes"] += rec.bytes_processed
             c["modeled_us"] += rec.modeled_time_us
-            c["wall_us"] += rec.wall_time_us
             if rec.link_hops > 0:
                 c["cross_ops"] += 1
                 c["cross_bytes"] += rec.bytes_processed
@@ -606,7 +616,6 @@ class StreamEngine:
                 fused_n = max(int(getattr(desc, "fused_n", 1) or 1), 1)
                 enqcmd_s = self.model.enqcmd_overhead_s / fused_n
         slot.record = rec
-        slot.t0 = time.perf_counter()
         slot.outputs = None
         tr = rec.trace
         if tr is not None:
@@ -621,10 +630,13 @@ class StreamEngine:
             # off the submitting thread, so a parked host is genuinely free
             if tr is not None:
                 tr.mark("exec0")
-            if isinstance(desc, BatchDescriptor):
+            if isinstance(desc, BatchDescriptor) and tr is None:
+                # untraced, the call an override of (b, dst_tier) takes too
                 outputs, nbytes, modeled = self._execute_batch(desc, dst_tier=dst_tier)
+            elif isinstance(desc, BatchDescriptor):
+                outputs, nbytes, modeled = self._execute_batch(desc, dst_tier, tr)
             else:
-                outputs, nbytes, modeled = self._execute_one(desc, dst_tier=dst_tier)
+                outputs, nbytes, modeled = self._execute_one(desc, dst_tier, tr)
             if tr is not None:
                 tr.mark("exec1")
             return outputs, nbytes, (modeled + enqcmd_s) * 1e6
@@ -651,75 +663,61 @@ class StreamEngine:
             kw.setdefault("link_hops", hops)
         return kw
 
-    def _execute_one(self, d: WorkDescriptor, dst_tier: str = "hbm"):
+    def _execute_one(self, d: WorkDescriptor, dst_tier: str = "hbm", tr=None):
+        """Run one descriptor's kernel; ``tr`` (its lifecycle trace, or
+        None) records the kernel call as the host span ``pe.kernel:<op>``."""
         it = self.interpret
-        m = self.model
         nbytes = d.nbytes
         # per-descriptor TO_CACHE hints steer like a to_cache WQ (G3)
         if d.cache_hint == CacheHint.TO_CACHE:
             dst_tier = "vmem"
         _, _, hops = self._locality(d)
-
-        def t_op(nb, **kw):
-            return m.op_time(nb, **self._model_kw(kw, dst_tier, hops))
-
+        if d.op == OpType.BATCH_COPY:
+            kw = {"batch_size": int(d.src_idx.shape[0])}
+        else:
+            kw = {"read_factor": _READ_FACTOR.get(d.op, 1.0)}
+        t = self.model.op_time(nbytes, **self._model_kw(kw, dst_tier, hops))
+        if d.op == OpType.CACHE_FLUSH:
+            return (), nbytes, t  # no TPU analogue (DESIGN.md); modeled only
+        t0 = time.perf_counter() if tr is not None else 0.0
         if d.op == OpType.MEMCPY:
             out = ops.memcpy(d.src, interpret=it)
-            t = t_op(nbytes)
         elif d.op == OpType.DUALCAST:
             out = ops.dualcast(d.src, interpret=it)
-            t = t_op(nbytes, read_factor=1.5)
         elif d.op == OpType.FILL:
             out = ops.fill(jnp.asarray(d.pattern, jnp.uint32), d.n_words, interpret=it)
-            t = t_op(nbytes, read_factor=0.5)  # write-only
         elif d.op == OpType.COMPARE:
             out = ops.compare(d.src, d.src2, interpret=it)
-            t = t_op(nbytes)
         elif d.op == OpType.COMPARE_PATTERN:
             out = ops.compare_pattern(d.src, jnp.asarray(d.pattern, jnp.uint32), interpret=it)
-            t = t_op(nbytes, read_factor=0.5)
         elif d.op == OpType.CRC32:
             out = ops.crc32(d.src, interpret=it)
-            t = t_op(nbytes, read_factor=0.5)
         elif d.op == OpType.DELTA_CREATE:
             out = ops.delta_create(d.src, d.src2, cap=d.cap, interpret=it)
-            t = t_op(nbytes)
         elif d.op == OpType.DELTA_APPLY:
             out = ops.delta_apply(d.src, d.src_idx, d.src2, interpret=it)
-            t = t_op(nbytes)
         elif d.op == OpType.DIF_INSERT:
             out = dif_ops.dif_insert(d.src, interpret=it)
-            t = t_op(nbytes)
         elif d.op == OpType.DIF_CHECK:
             out = dif_ops.dif_check(d.src, interpret=it)
-            t = t_op(nbytes, read_factor=0.5)
         elif d.op == OpType.DIF_STRIP:
             out = dif_ops.dif_strip(d.src)
-            t = t_op(nbytes)
         elif d.op == OpType.BATCH_COPY:
             out = ops.batch_copy(d.src, d.dst_pool, d.src_idx, d.dst_idx, interpret=it)
-            t = t_op(nbytes, batch_size=int(d.src_idx.shape[0]))
         elif d.op == OpType.COPY_CRC:
-            # fused memcpy+CRC32: one launch, one read pass feeding both the
-            # write stream and the checksum — vs two launches and two read
-            # passes (memcpy at 1.0 + crc32 at 0.5) unfused
             out = ops.copy_crc(d.src, interpret=it)
-            t = t_op(nbytes)
         elif d.op == OpType.FILL_VERIFY:
-            # fused fill+compare_pattern: the verify reads the tile just
-            # written in-kernel, so the pair costs one fill (0.5) instead of
-            # fill + compare_pattern (0.5 + 0.5) across two launches
-            out = ops.fill_verify(jnp.asarray(d.pattern, jnp.uint32),
-                                  d.n_words, interpret=it)
-            t = t_op(nbytes, read_factor=0.5)
-        elif d.op == OpType.CACHE_FLUSH:
-            out = ()  # no TPU analogue (DESIGN.md); modeled only
-            t = t_op(nbytes, read_factor=0.5)
+            out = ops.fill_verify(jnp.asarray(d.pattern, jnp.uint32), d.n_words, interpret=it)
         else:
             raise ValueError(f"unsupported op {d.op}")
+        if tr is not None:
+            tr.record_span(f"pe.kernel:{d.op.value}", t0)
         return out, nbytes, t
 
-    def _execute_batch(self, b: BatchDescriptor, dst_tier: str = "hbm"):
+    def _execute_batch(self, b: BatchDescriptor, dst_tier: str = "hbm", tr=None):
+        """Run a batch, fused where it can be; ``tr`` (its lifecycle trace,
+        or None) records the kernel calls and the fused path's steps as
+        host spans."""
         descs = list(b.descriptors)
         # F2 fusion: homogeneous same-shape copies -> ONE batch_copy launch.
         # Fuse only when per-descriptor flags agree: a mixed cache-hint batch
@@ -735,20 +733,32 @@ class StreamEngine:
         ):
             if descs[0].cache_hint == CacheHint.TO_CACHE:
                 dst_tier = "vmem"
-            pool = jnp.stack([d.src for d in descs])
             idx = jnp.arange(len(descs), dtype=jnp.int32)
-            out = ops.batch_copy(pool, jnp.zeros_like(pool), idx, idx, interpret=self.interpret)
+            if tr is None:
+                pool = jnp.stack([d.src for d in descs])
+                out = list(ops.batch_copy(pool, jnp.zeros_like(pool), idx, idx,
+                                          interpret=self.interpret))
+            else:
+                t = time.perf_counter()
+                pool = jnp.stack([d.src for d in descs])
+                t = tr.record_span("pe.stack", t)
+                dst = jnp.zeros_like(pool)
+                t = tr.record_span("pe.zeros", t)
+                out = ops.batch_copy(pool, dst, idx, idx, interpret=self.interpret)
+                t = tr.record_span("pe.kernel:batch_copy", t)
+                out = list(out)
+                tr.record_span("pe.unstack", t)
             nbytes = b.nbytes
             _, _, hops = self._locality(b)
             t = self.model.op_time(descs[0].nbytes,
                                    **self._model_kw({"batch_size": len(descs)},
                                                     dst_tier, hops))
-            return list(out), nbytes, t
+            return out, nbytes, t
         outs = []
         nbytes = 0
         t = self.model.launch_overhead_s
         for d in descs:
-            o, nb, td = self._execute_one(d, dst_tier=dst_tier)
+            o, nb, td = self._execute_one(d, dst_tier, tr)
             outs.append(o)
             nbytes += nb
             t += td - self.model.launch_overhead_s + self.model.submit_overhead_s

@@ -13,7 +13,9 @@ every emitted line is strict JSON (Python's default would write bare
 loadable as-is in chrome://tracing or https://ui.perfetto.dev: one process
 track for the host plus one per engine, one thread lane per descriptor,
 complete ("X") slices per lifecycle phase, flow arrows for ``after=`` /
-``then`` dependency edges, and a host lane of WaitPolicy wait spans.
+``then`` dependency edges, and a ``runtime`` track with one lane per
+thread for the tracer's host spans (waits, PE kernel calls, KV-pool steps,
+garbage collections).
 """
 from __future__ import annotations
 
@@ -59,23 +61,30 @@ def to_jsonl(sampler, path: Optional[str] = None) -> str:
 
 
 def to_perfetto(tracer, path: Optional[str] = None, *,
-                flows: bool = True) -> str:
+                flows: bool = True, clock=None) -> str:
     """Render a Tracer's retained traces as Chrome/Perfetto trace_event
     JSON ({"traceEvents": [...]}); optionally also write to ``path``.
 
-    Layout: pid 1 is the host (tid = descriptor id per lane, tid 0 holds
-    the WaitPolicy wait spans); each engine that dispatched a sampled
-    descriptor gets its own pid.  Timestamps are microseconds from the
-    earliest retained mark, clamped non-negative with dur >= 0, so the
-    file always passes strict-JSON and monotonicity validation."""
+    Layout: pid 1 is the host (tid = descriptor id per lane); each engine
+    that dispatched a sampled descriptor gets its own pid, and the
+    ``runtime`` pid holds the host spans, one lane per thread.
+    Timestamps are microseconds from the earliest retained mark, or, with
+    ``clock`` (a ``repro.obs.ClockMap``), microseconds on that device
+    profile's clock, which is the clock of the profiler's own
+    ``perfetto_trace.json.gz``; they are clamped non-negative with dur >=
+    0, so the file always passes strict-JSON and monotonicity validation."""
     traces = tracer.traces()
-    waits = tracer.wait_spans()
-    starts = [dt.start for dt in traces if dt.marks]
-    starts += [w.t0 for w in waits]
-    base = min(starts, default=0.0)
+    host = tracer.host_spans()
+    if clock is None:
+        starts = [dt.start for dt in traces if dt.marks]
+        starts += [sp.t0 for sp in host]
+        base = min(starts, default=0.0)
 
-    def us(t: float) -> float:
-        return round(max((t - base) * 1e6, 0.0), 3)
+        def us(t: float) -> float:
+            return round(max((t - base) * 1e6, 0.0), 3)
+    else:
+        def us(t: float) -> float:
+            return round(max(clock.to_profile(t) / 1e3, 0.0), 3)
 
     pids: Dict[str, int] = {"host": 1}
 
@@ -124,25 +133,30 @@ def to_perfetto(tracer, path: Optional[str] = None, *,
                 "ts": us(max(cdt.start, pdt.end)),
                 "pid": pids["host"], "tid": int(child),
             })
-    for w in waits:
+    threads: Dict[str, int] = {}
+    for sp in host:
+        tid = threads.setdefault(sp.thread, len(threads) + 1)
+        args = {k: _json_safe(v) for k, v in sp.attrs.items()}
+        if sp.desc_id is not None:
+            args["desc_id"] = sp.desc_id
+            args["trace_id"] = sp.trace_id
         events.append({
-            "name": f"wait/{w.policy}",
-            "cat": "wait",
+            "name": sp.name,
+            "cat": sp.name.split(".", 1)[0],
             "ph": "X",
-            "ts": us(w.t0),
-            "dur": round(max(w.t1 - w.t0, 0.0) * 1e6, 3),
-            "pid": pids["host"],
-            "tid": 0,
-            "args": {"busy_s": _json_safe(w.busy_s),
-                     "free_s": _json_safe(w.free_s),
-                     "completions": w.completions},
+            "ts": us(sp.t0),
+            "dur": round(max(sp.t1 - sp.t0, 0.0) * 1e6, 3),
+            "pid": pid_for("runtime"),
+            "tid": tid,
+            "args": args,
         })
     for track, pid in pids.items():
         events.append({"name": "process_name", "ph": "M", "pid": pid,
                        "tid": 0, "args": {"name": f"dsa-repro/{track}"}})
-    if waits:
-        events.append({"name": "thread_name", "ph": "M", "pid": pids["host"],
-                       "tid": 0, "args": {"name": "waits"}})
+    for thread, tid in threads.items():
+        events.append({"name": "thread_name", "ph": "M",
+                       "pid": pids["runtime"], "tid": tid,
+                       "args": {"name": thread}})
     text = json.dumps({"traceEvents": events, "displayTimeUnit": "ms"},
                       sort_keys=True, allow_nan=False)
     if path is not None:

@@ -254,6 +254,13 @@ class Sampler:
                 tr_prev = self._prev.get("trace", {})
                 self._record(row, "trace.sampled",
                              tr_cur["sampled"] - tr_prev.get("sampled", 0), t)
+                # time the process spent in garbage collections this tick,
+                # and what the tracer's full rings rotated out
+                self._record(row, "trace.gc_pause_ms",
+                             1e3 * (tr_cur["gc.pause_s"]
+                                    - tr_prev.get("gc.pause_s", 0.0)), t, "ms")
+                self._record(row, "trace.dropped",
+                             tr_cur["dropped"] - tr_prev.get("dropped", 0), t)
                 # live phase occupancy: seconds of each lifecycle phase
                 # completed per wall second this tick (the pcm_repro
                 # phases line; >1 means parallel descriptors in flight)

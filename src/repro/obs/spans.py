@@ -23,6 +23,11 @@ Marks are written causally along the descriptor's path (submit thread ->
 arbiter -> PE worker -> retire thread -> observer), each exactly once, so
 a plain dict is safe under the GIL; ``clean_marks`` clamps any residual
 cross-thread clock skew so derived spans are always monotonic.
+
+Work done on a descriptor's behalf inside a phase (the PE worker's kernel
+calls) is recorded as host spans of the tracer, tagged with the
+descriptor's ids: ``t0 = time.perf_counter(); ...;
+trace.record_span("pe.kernel:memcpy", t0)``.
 """
 from __future__ import annotations
 
@@ -141,6 +146,21 @@ class DescTrace:
         if name in ("resolved", "observed", "cb1") and self._tracer is not None:
             self._tracer._fold(self)
         return t
+
+    @property
+    def tracer(self) -> Optional[Any]:
+        """The ``Tracer`` this trace belongs to (None for a detached one)."""
+        return self._tracer
+
+    def record_span(self, name: str, t0: float) -> float:
+        """Record ``[t0, now]`` as the tracer's host span ``name``, tagged
+        with this descriptor's ``desc_id``/``trace_id``; returns now, so
+        that consecutive steps chain."""
+        t1 = time.perf_counter()
+        if self._tracer is not None:
+            self._tracer.record(name, t0, t1, desc_id=self.desc_id,
+                                trace_id=self.trace_id)
+        return t1
 
     @property
     def start(self) -> Optional[float]:

@@ -14,9 +14,13 @@ Design constraints, in order:
 
   * hot path untouched when off: ``Device.submit`` does one attribute
     check; an unsampled submission costs one accumulator update;
-  * bounded memory: traces / edges / wait spans live in fixed-capacity
-    deques, while per-phase occupancy folds into MONOTONIC counters the
-    ``Sampler`` delta-ticks (so live views survive ring rotation);
+  * bounded memory: traces / edges / host spans live in fixed-capacity
+    deques (what rotates out is counted in ``dropped``); a host span is
+    kept as a plain tuple of atomic values, which the collector stops
+    tracking after its first collection, so a full ring of spans adds
+    nothing to later collections' work; per-phase
+    occupancy folds into MONOTONIC counters the ``Sampler`` delta-ticks
+    (so live views survive ring rotation);
   * deterministic sampling: a fractional accumulator admits exactly
     ``rate`` of anonymous submissions (no RNG), and request-scoped
     contexts (``tracer.request(id)``) decide once per request id via a
@@ -30,9 +34,12 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
+import gc
 import threading
+import time
+import weakref
 import zlib
-from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple, Union
+from typing import Any, Dict, Iterable, List, Optional, Tuple, Union
 
 from repro.obs.series import percentile
 from repro.obs.spans import PHASES, DescTrace
@@ -60,7 +67,7 @@ class TraceRateError(ValueError):
 class TraceConfig:
     """Tracer knobs: sampling ``rate`` in [0, 1] (fraction of submissions
     traced; request contexts decide per request id) and ring ``capacity``
-    (retained traces; edges/wait spans keep a few multiples)."""
+    (retained traces; edges and host spans keep 8 x as many)."""
 
     rate: float = 1.0
     capacity: int = 4096
@@ -77,18 +84,27 @@ class TraceConfig:
                              f"got {self.capacity}")
 
 
-@dataclasses.dataclass
-class WaitSpan:
-    """One WaitPolicy.wait interval with its host-cycle split — the same
-    busy/free seconds the policy folds into the device's ``WaitStats``
-    bucket, so span-derived host-free fractions reconcile exactly."""
+@dataclasses.dataclass(slots=True)
+class HostSpan:
+    """One interval of the runtime's own host work, on the ``perf_counter``
+    clock of the lifecycle marks: ``name`` (``wait.<policy>``,
+    ``pe.kernel:<op>``, ``kvpool.plan``, ``gc.gen2`` ...), the thread it
+    ran on, the descriptor (``desc_id``/``trace_id``) it belongs to where
+    it belongs to one, and ``attrs`` (a wait's busy/free split, a
+    collection's generation).  The ring keeps the same fields as a plain
+    tuple; ``Tracer.host_spans`` returns them as HostSpans."""
 
-    policy: str
+    name: str
     t0: float
     t1: float
-    busy_s: float
-    free_s: float
-    completions: int = 0
+    thread: str = ""
+    desc_id: Optional[int] = None
+    trace_id: Optional[str] = None
+    attrs: Dict[str, Any] = dataclasses.field(default_factory=dict)
+
+
+#: name of the profiler annotation ``Tracer.anchor`` enters
+ANCHOR = "dsa.clock"
 
 
 def _op_name(desc: Any) -> str:
@@ -105,19 +121,29 @@ class Tracer:
         self.config = config or TraceConfig()
         cap = self.config.capacity
         # plain (uninstrumented) leaf lock: the tracer never calls out
-        # while holding it, so it cannot extend the lockcheck lock graph
-        self._lock = threading.Lock()
+        # while holding it, so it cannot extend the lockcheck lock graph.
+        # Re-entrant because a collection can start on a thread that holds
+        # it, and the gc hook then records on that same thread.
+        self._lock = threading.RLock()
         self._ring: "collections.deque[DescTrace]" = collections.deque(maxlen=cap)
         self._edges: "collections.deque[Tuple[int, int, str]]" = (
             collections.deque(maxlen=8 * cap))
-        self._waits: "collections.deque[WaitSpan]" = (
+        # (name, t0, t1, thread, desc_id, trace_id, key, value, key, ...)
+        self._spans: "collections.deque[tuple]" = (
             collections.deque(maxlen=8 * cap))
+        self._anchors: List[int] = []
         self._acc = 0.0  # fractional sampling accumulator
         self._tls = threading.local()
+        # gc.callbacks hook, installed while a device holds the tracer
+        self._gc_hook: Optional[Any] = None
+        self._holders = 0
+        self._gc_t0: Optional[float] = None
         # monotonic counters (delta-sampled by repro.obs.Sampler)
         self.counters: Dict[str, float] = {
-            "sampled": 0, "skipped": 0,
+            "sampled": 0, "skipped": 0, "dropped": 0,
             "wait_spans": 0, "wait_busy_s": 0.0, "wait_free_s": 0.0,
+            "gc.pause_s": 0.0, "gc.collections.gen0": 0,
+            "gc.collections.gen1": 0, "gc.collections.gen2": 0,
         }
         self.phase_s: Dict[str, float] = {p: 0.0 for p in PHASES}
         self.phase_n: Dict[str, int] = {p: 0 for p in PHASES}
@@ -193,7 +219,7 @@ class Tracer:
             dt.marks["create"] = t_create
         dt.mark("submit_enter")
         with self._lock:
-            self._ring.append(dt)
+            self._append(self._ring, dt)
             self.counters["sampled"] += 1
         return dt
 
@@ -204,7 +230,7 @@ class Tracer:
         dt.attrs["kind"] = "then"
         dt.mark("create")
         with self._lock:
-            self._ring.append(dt)
+            self._append(self._ring, dt)
             self.counters["sampled"] += 1
         return dt
 
@@ -212,17 +238,93 @@ class Tracer:
         """Record a dependency edge ("after" fence or "then" continuation)
         for the critical-path DAG."""
         with self._lock:
-            self._edges.append((int(parent_desc_id), int(child_desc_id), kind))
+            self._append(self._edges,
+                         (int(parent_desc_id), int(child_desc_id), kind))
+
+    def _append(self, ring: collections.deque, item: Any) -> None:
+        """Append under ``_lock``, counting what a full ring rotates out."""
+        if len(ring) == ring.maxlen:
+            self.counters["dropped"] += 1
+        ring.append(item)
+
+    def record(self, name: str, t0: float, t1: float, *,
+               desc_id: Optional[int] = None, trace_id: Optional[str] = None,
+               **attrs: Any) -> None:
+        """Record one host span ``[t0, t1]`` (``perf_counter`` seconds) on
+        the calling thread; ``attrs`` take plain numbers and strings."""
+        sp = (name, t0, t1, threading.current_thread().name, desc_id, trace_id)
+        if attrs:
+            sp += tuple(x for kv in attrs.items() for x in kv)
+        with self._lock:
+            self._append(self._spans, sp)
 
     def wait_span(self, policy: str, t0: float, t1: float,
                   busy_s: float, free_s: float, completions: int = 0) -> None:
+        """One WaitPolicy.wait as the host span ``wait.<policy>``, with the
+        same busy/free seconds the policy folds into the device's
+        ``WaitStats`` bucket, so ``host_free_fraction`` reconciles exactly."""
         with self._lock:
-            self._waits.append(WaitSpan(policy, t0, t1, busy_s, free_s,
-                                        completions))
+            self.record(f"wait.{policy}", t0, t1, busy_s=busy_s,
+                        free_s=free_s, completions=completions)
             c = self.counters
             c["wait_spans"] += 1
             c["wait_busy_s"] += busy_s
             c["wait_free_s"] += free_s
+
+    # ------------------------------------------------------------------ clock
+    def anchor(self) -> int:
+        """Enter the profiler annotation ``dsa.clock`` and keep the
+        ``perf_counter_ns()`` taken at its entry.  Two anchors, one at each
+        end of a profiled window, map every mark and host span of the
+        window onto the profile's clock (``repro.obs.clock.ClockMap``)."""
+        from jax.profiler import TraceAnnotation
+
+        with TraceAnnotation(ANCHOR):
+            t = time.perf_counter_ns()
+        with self._lock:
+            self._anchors.append(t)
+        return t
+
+    def anchors(self) -> List[int]:
+        with self._lock:
+            return list(self._anchors)
+
+    # ------------------------------------------------------------------ gc hook
+    def attach(self) -> None:
+        """A device takes the tracer: while any holds it, every garbage
+        collection is recorded as a ``gc.gen<N>`` span and counted."""
+        with self._lock:
+            self._holders += 1
+            if self._gc_hook is None:
+                self._gc_hook = _gc_hook(self)
+                gc.callbacks.append(self._gc_hook)
+
+    def close(self) -> None:
+        """A device lets the tracer go; the last one removes the gc hook."""
+        with self._lock:
+            self._holders = max(self._holders - 1, 0)
+            hook = self._gc_hook if self._holders == 0 else None
+            if hook is not None:
+                self._gc_hook = None
+        if hook is not None and hook in gc.callbacks:
+            gc.callbacks.remove(hook)
+
+    def _on_gc(self, phase: str, info: Dict[str, Any]) -> None:
+        # collections never nest, so one start time serves every thread
+        if phase == "start":
+            self._gc_t0 = time.perf_counter()
+            return
+        t0, self._gc_t0 = self._gc_t0, None
+        if t0 is None:  # hooked in mid-collection
+            return
+        t1 = time.perf_counter()
+        gen = info["generation"]
+        with self._lock:
+            self.record(f"gc.gen{gen}", t0, t1, generation=gen,
+                        collected=info["collected"])
+            c = self.counters
+            c["gc.pause_s"] += t1 - t0
+            c[f"gc.collections.gen{gen}"] += 1
 
     def _fold(self, dt: DescTrace) -> None:
         """Fold ``dt``'s newly-completed phases into the monotonic
@@ -246,9 +348,11 @@ class Tracer:
         with self._lock:
             return list(self._edges)
 
-    def wait_spans(self) -> List[WaitSpan]:
+    def host_spans(self, prefix: str = "") -> List[HostSpan]:
+        """Retained host spans whose name starts with ``prefix``."""
         with self._lock:
-            return list(self._waits)
+            kept = [sp for sp in self._spans if sp[0].startswith(prefix)]
+        return [HostSpan(*sp[:6], dict(zip(sp[6::2], sp[7::2]))) for sp in kept]
 
     def counters_snapshot(self) -> Dict[str, float]:
         """Monotonic counters incl. per-phase folded seconds/counts
@@ -259,6 +363,26 @@ class Tracer:
                 snap[f"phase.{p}_s"] = self.phase_s[p]
                 snap[f"phase.{p}_n"] = float(self.phase_n[p])
             return snap
+
+
+def _gc_hook(tracer: Tracer):
+    """A ``gc.callbacks`` entry that holds ``tracer`` weakly, so a traced
+    device dropped without ``close()`` leaves neither its tracer nor the
+    hook behind."""
+    ref = weakref.ref(tracer)
+
+    def hook(phase: str, info: Dict[str, Any]) -> None:
+        t = ref()
+        if t is not None:
+            t._on_gc(phase, info)
+
+    weakref.finalize(tracer, _remove_gc_hook, hook)
+    return hook
+
+
+def _remove_gc_hook(hook) -> None:
+    if hook in gc.callbacks:
+        gc.callbacks.remove(hook)
 
 
 def make_tracer(spec: Union[None, bool, int, float, TraceConfig, Tracer]
@@ -389,3 +513,4 @@ def slowest(tracer_or_traces: Union[Tracer, Iterable[DescTrace]],
     """The k traces with the largest span extent, slowest first."""
     traces = [t for t in _as_traces(tracer_or_traces) if t.marks]
     return sorted(traces, key=lambda t: t.duration_s, reverse=True)[:k]
+

@@ -18,6 +18,7 @@ Vhost-style serving pipeline.
 from __future__ import annotations
 
 import dataclasses
+import time
 from collections import defaultdict
 from typing import Dict, List, Optional, Tuple
 
@@ -26,6 +27,13 @@ import jax.numpy as jnp
 
 from repro.core.topology import Topology
 from repro.kernels import ops as kops
+
+
+def _upload_indices(plan) -> List[Tuple[jax.Array, jax.Array]]:
+    """The device index arrays (source pages, destination pages) of each
+    ``(node, [(slot, page)], dst_pages)`` group of a swap plan."""
+    return [(jnp.asarray([p for _, p in group], jnp.int32),
+             jnp.asarray(dst, jnp.int32)) for _, group, dst in plan]
 
 
 @dataclasses.dataclass
@@ -202,7 +210,11 @@ class PagedKVPool:
         """Device -> host: one batch descriptor PER SOURCE NODE.  Free-list
         pops are restored if any copy fails, so a raising batch copy leaks
         no pages (the pools and page table only commit after every copy
-        succeeded)."""
+        succeeded).  On a traced device the host work before the copies
+        (free-list pops, index uploads) is the span ``kvpool.plan``, and
+        the page-table and free-list update after them ``kvpool.commit``."""
+        tracer = getattr(self.device, "tracer", None)
+        t0 = time.perf_counter() if tracer is not None else 0.0
         entries = self.page_table.get(seq_id, [])
         by_node: Dict[int, List[Tuple[int, int]]] = defaultdict(list)
         for slot, (tier, node, p) in enumerate(entries):
@@ -221,10 +233,11 @@ class PagedKVPool:
             plan.append((node, group, host_pages[cursor:cursor + len(group)]))
             cursor += len(group)
         try:
+            idx = _upload_indices(plan)
+            if tracer is not None:
+                tracer.record("kvpool.plan", t0, time.perf_counter())
             new_host = self.host_pool
-            for node, group, dst in plan:
-                src_idx = jnp.asarray([p for _, p in group], jnp.int32)
-                dst_idx = jnp.asarray(dst, jnp.int32)
+            for (node, _, _), (src_idx, dst_idx) in zip(plan, idx):
                 new_host = self._batch_copy(self.device_pools[node], new_host,
                                             src_idx, dst_idx,
                                             dst_node=self.host_node)
@@ -232,6 +245,7 @@ class PagedKVPool:
             # restore the pops in reverse so the free list is byte-identical
             self._free_host.extend(reversed(host_pages))
             raise
+        t0 = time.perf_counter() if tracer is not None else 0.0
         self._set_host_pool(new_host)
         for node, group, dst in plan:
             for (slot, p), hp in zip(group, dst):
@@ -243,13 +257,18 @@ class PagedKVPool:
             1 for n, _, _ in plan if n != self.host_node)
         self.stats.pages_moved += total
         self._count()
+        if tracer is not None:
+            tracer.record("kvpool.commit", t0, time.perf_counter())
         return True
 
     def swap_in(self, seq_id: int, node: Optional[int] = None) -> bool:
         """Host -> device: one batch descriptor PER DESTINATION NODE, for
         scheduling a sequence.  ``node`` pins the landing node; otherwise
-        pages land greedily on the freest nodes.  Same no-leak contract as
-        ``swap_out``: pops restore on failure, state commits on success."""
+        pages land greedily on the freest nodes.  Same no-leak contract
+        and spans as ``swap_out``: pops restore on failure, state commits
+        on success."""
+        tracer = getattr(self.device, "tracer", None)
+        t0 = time.perf_counter() if tracer is not None else 0.0
         entries = self.page_table.get(seq_id, [])
         host = [(slot, p) for slot, (t, _n, p) in enumerate(entries) if t == "host"]
         if not host:
@@ -273,10 +292,11 @@ class PagedKVPool:
             if cursor == len(host):
                 break
         try:
+            idx = _upload_indices(plan)
+            if tracer is not None:
+                tracer.record("kvpool.plan", t0, time.perf_counter())
             new_pools: Dict[int, jax.Array] = {}
-            for n, group, dst in plan:
-                src_idx = jnp.asarray([p for _, p in group], jnp.int32)
-                dst_idx = jnp.asarray(dst, jnp.int32)
+            for (n, _, _), (src_idx, dst_idx) in zip(plan, idx):
                 new_pools[n] = self._batch_copy(
                     self.host_pool, new_pools.get(n, self.device_pools[n]),
                     src_idx, dst_idx, dst_node=n)
@@ -284,6 +304,7 @@ class PagedKVPool:
             for n, dst in popped.items():
                 self._free_device[n].extend(reversed(dst))
             raise
+        t0 = time.perf_counter() if tracer is not None else 0.0
         for n, pool in new_pools.items():
             self._set_device_pool(n, pool)
         for n, group, dst in plan:
@@ -296,4 +317,6 @@ class PagedKVPool:
             1 for n, _, _ in plan if n != self.host_node)
         self.stats.pages_moved += len(host)
         self._count()
+        if tracer is not None:
+            tracer.record("kvpool.commit", t0, time.perf_counter())
         return True

@@ -1,5 +1,7 @@
 """Descriptor-lifecycle tracing: span model, sampling, dependency edges,
-critical path, host-free reconciliation, and the Perfetto export."""
+critical path, host-free reconciliation, the runtime's host spans and gc
+hook, the clock shared with a device profile, and the Perfetto export."""
+import gc
 import json
 
 import jax.numpy as jnp
@@ -10,11 +12,13 @@ from repro.core.descriptor import BatchDescriptor
 from repro.obs import (
     HOST_PHASES,
     PHASES,
+    ClockMap,
     DescTrace,
     TraceConfig,
     Tracer,
     TraceRateError,
     critical_path,
+    device_lead_ns,
     host_free_fraction,
     make_tracer,
     phase_breakdown,
@@ -267,6 +271,10 @@ def test_host_free_fraction_matches_waitstats_exactly(buf):
     ws_frac = free / (busy + free)
     assert spans_frac == pytest.approx(ws_frac, rel=1e-9)
     assert abs(spans_frac - ws_frac) <= 0.05 * max(ws_frac, 1e-12)
+    # the merged span record carries the same split, wait by wait
+    waits = device.tracer.host_spans("wait.")
+    assert sum(w.attrs["busy_s"] for w in waits) == pytest.approx(busy, rel=1e-9)
+    assert sum(w.attrs["free_s"] for w in waits) == pytest.approx(free, rel=1e-9)
 
 
 def test_wait_spans_recorded_per_wait(buf):
@@ -274,11 +282,12 @@ def test_wait_spans_recorded_per_wait(buf):
     fut = device.memcpy_async(buf)
     fut.wait()
     device.drain()
-    waits = device.tracer.wait_spans()
+    waits = device.tracer.host_spans("wait.")
     assert waits
     for w in waits:
-        assert w.t1 >= w.t0
-        assert w.busy_s >= 0.0 and w.free_s >= 0.0
+        assert w.name == "wait.umwait"
+        assert w.t1 >= w.t0 and w.thread
+        assert w.attrs["busy_s"] >= 0.0 and w.attrs["free_s"] >= 0.0
 
 
 # --------------------------------------------------------------------- perfetto
@@ -303,7 +312,7 @@ def test_perfetto_valid_json_and_monotonic(buf, tmp_path):
     slices = [ev for ev in events if ev.get("ph") == "X"]
     names = {ev["name"] for ev in slices}
     assert set(PHASES) <= names
-    assert any(ev["name"].startswith("wait/") for ev in slices)
+    assert any(ev["name"].startswith("wait.") for ev in slices)
     # flow arrows for both edge kinds, start before finish
     flows = {}
     for ev in events:
@@ -358,3 +367,225 @@ def test_queuefull_trace_is_terminated_not_leaked(buf):
         assert errored
         for dt in errored:
             assert "resolved" in dt.marks  # terminated, not dangling
+
+
+# --------------------------------------------------------------------- host spans
+def test_unfused_batch_records_a_kernel_span_per_member(buf):
+    device = _traced_device()
+    descs = [WorkDescriptor(op=OpType.MEMCPY, src=jnp.zeros((n, 128), jnp.float32))
+             for n in (8, 16, 8)]  # mixed shapes: the unfused path
+    fut = device.submit(BatchDescriptor(descriptors=descs))
+    fut.wait()
+    device.drain()
+    dt = fut.trace
+    kernels = device.tracer.host_spans("pe.kernel:")
+    assert [sp.name for sp in kernels] == ["pe.kernel:memcpy"] * 3
+    for sp in kernels:
+        assert (sp.desc_id, sp.trace_id) == (dt.desc_id, dt.trace_id)
+        assert dt.marks["exec0"] <= sp.t0 <= sp.t1 <= dt.marks["exec1"]
+        assert sp.thread.startswith("pe")
+    assert not device.tracer.host_spans("pe.stack")
+
+
+def test_fused_batch_records_its_glue_and_one_kernel_span(buf):
+    device = _traced_device()
+    descs = [WorkDescriptor(op=OpType.MEMCPY, src=buf) for _ in range(4)]
+    fut = device.submit(BatchDescriptor(descriptors=descs))
+    fut.wait()
+    device.drain()
+    pe = [sp for sp in device.tracer.host_spans("pe.")]
+    assert [sp.name for sp in pe] == ["pe.stack", "pe.zeros",
+                                      "pe.kernel:batch_copy", "pe.unstack"]
+    assert all(a.t1 <= b.t0 for a, b in zip(pe, pe[1:]))
+    assert {sp.desc_id for sp in pe} == {fut.trace.desc_id}
+
+
+def test_kv_pool_swaps_record_plan_and_commit():
+    from repro.serving.kv_pool import PagedKVPool
+
+    device = _traced_device()
+    kv = PagedKVPool(4, 4, 8, 128, device=device)
+    assert kv.alloc(0, 3)
+    assert kv.swap_out(0) and kv.swap_in(0)
+    tracer = device.tracer
+    plans, commits = tracer.host_spans("kvpool.plan"), tracer.host_spans("kvpool.commit")
+    assert len(plans) == len(commits) == 2
+    copies = [dt for dt in tracer.traces() if dt.op == "batch_copy"]
+    assert len(copies) == 2
+    # plan before its descriptor is created, commit after it is observed
+    for plan, dt, commit in zip(plans, copies, commits):
+        assert plan.t1 <= dt.marks["submit_enter"]
+        assert dt.marks["observed"] <= commit.t0
+
+
+def test_untraced_device_leaves_gc_callbacks_alone(buf):
+    gc.collect()  # earlier tests' dropped devices take their hooks along
+    before = list(gc.callbacks)
+    device = make_device(n_instances=1)
+    fut = device.submit(BatchDescriptor(descriptors=[
+        WorkDescriptor(op=OpType.MEMCPY, src=buf) for _ in range(2)]))
+    fut.wait()
+    gc.collect()
+    device.close()
+    assert device.tracer is None and fut.trace is None
+    assert gc.callbacks == before
+
+
+def test_gc_hook_records_collections_until_close(buf):
+    gc.collect()  # earlier tests' dropped devices take their hooks along
+    before = list(gc.callbacks)
+    device = _traced_device()
+    assert len(gc.callbacks) == len(before) + 1
+    gc.collect()
+    tracer = device.tracer
+    spans = tracer.host_spans("gc.")
+    assert any(sp.name == "gc.gen2" and sp.attrs["generation"] == 2 for sp in spans)
+    c = tracer.counters_snapshot()
+    assert c["gc.collections.gen2"] >= 1
+    assert c["gc.pause_s"] == pytest.approx(sum(sp.t1 - sp.t0 for sp in spans))
+    device.close()
+    device.close()  # idempotent
+    assert gc.callbacks == before
+    n = len(tracer.host_spans("gc."))
+    gc.collect()
+    assert len(tracer.host_spans("gc.")) == n
+
+
+def test_a_dropped_traced_device_takes_its_gc_hook_along():
+    gc.collect()  # earlier tests' dropped devices take their hooks along
+    before = list(gc.callbacks)
+    device = _traced_device()
+    del device
+    gc.collect()
+    assert gc.callbacks == before
+
+
+def test_rings_count_what_they_drop(buf):
+    device = _traced_device()
+    futs = [device.memcpy_async(buf) for _ in range(20)]
+    device.wait_all(futs)
+    device.drain()
+    assert device.tracer.counters_snapshot()["dropped"] == 0
+    small = _traced_device(trace=TraceConfig(rate=1.0, capacity=8))
+    futs = [small.memcpy_async(buf) for _ in range(20)]
+    small.wait_all(futs)
+    small.drain()
+    # 20 traces in a ring of 8, and 8 x 8 host spans hold every kernel
+    # call and wait of this run
+    assert small.tracer.counters_snapshot()["dropped"] >= 12
+
+
+# --------------------------------------------------------------------- clock
+def test_clock_map_fit():
+    cm = ClockMap.fit([1_000, 3_000], [10, 2_010])
+    assert (cm.rate, cm.offset_ns) == (1.0, -990.0)
+    assert cm.to_profile(2e-6) == pytest.approx(1_010)
+    assert cm.to_perf(1_010) == pytest.approx(2e-6)
+    # more anchors: the first and the last fix the line
+    three = ClockMap.fit([0, 1e9, 2e9], [5, 1e9 + 900, 4e9 + 5])
+    assert (three.rate, three.offset_ns) == (2.0, 5.0)
+    for perf, prof in (([1], [1]), ([1, 2], [1]), ([4, 4], [1, 2])):
+        with pytest.raises(ValueError):
+            ClockMap.fit(perf, prof)
+
+
+def test_anchors_map_marks_onto_the_profile_clock(tmp_path):
+    """Marks taken inside known annotations on two threads land within
+    50 us of the annotations' starts in the profile."""
+    import glob
+    import threading
+    import time
+
+    import jax
+    from jax.profiler import ProfileData, TraceAnnotation
+
+    tracer = Tracer()
+    marks = {}
+
+    def work(k):
+        for i in range(4):
+            with TraceAnnotation(f"probe{k}.{i}"):
+                marks[f"probe{k}.{i}"] = time.perf_counter()
+            time.sleep(0.005)
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        tracer.anchor()
+        threads = [threading.Thread(target=work, args=(k,)) for k in range(2)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=30)
+        time.sleep(0.05)
+        tracer.anchor()
+    finally:
+        jax.profiler.stop_trace()
+    assert not any(t.is_alive() for t in threads)
+    profile = ProfileData.from_file(glob.glob(f"{tmp_path}/plugins/profile/*/*.xplane.pb")[0])
+    starts = {e.name: e.start_ns for plane in profile.planes for line in plane.lines
+              for e in line.events if e.name.startswith("probe")}
+    assert set(starts) == set(marks)
+    clock = ClockMap.between(tracer, profile)
+    for name, t in marks.items():
+        assert abs(clock.to_profile(t) - starts[name]) < 50_000, name
+    # the Perfetto export on that clock: microseconds of the profile
+    tracer.record("probe.span", marks["probe0.0"], marks["probe0.1"])
+    doc = json.loads(to_perfetto(tracer, clock=clock))
+    ev = next(e for e in doc["traceEvents"] if e["name"] == "probe.span")
+    assert abs(ev["ts"] - starts["probe0.0"] / 1e3) < 50
+
+
+def test_device_lead_puts_no_program_before_its_call():
+    # the device plane reads 1.4 ms behind; programs start 0 to 0.3 ms
+    # after their calls
+    delays = [0.0, 0.1e6, 0.3e6, 0.2e6, 0.05e6]
+    calls = [100e6 * i for i in range(1, 6)]
+    starts = [c - 1.4e6 + d for c, d in zip(calls, delays)]
+    assert device_lead_ns(calls, starts) == pytest.approx(1.4e6)
+    assert device_lead_ns([], starts) is None
+    assert device_lead_ns(calls, []) is None
+    cm = ClockMap.fit([0, 1_000], [0, 1_000])
+    assert cm.shifted(-1.3e6).to_profile(1e-3) == pytest.approx(1e6 - 1.3e6)
+
+
+# --------------------------------------------------------------------- memory
+def test_host_spans_leave_the_collectors_tracking():
+    """A retained span holds only numbers and strings, so the first
+    collection it survives stops tracking it: a full ring adds nothing to
+    later collections' work."""
+    tracer = Tracer()
+    tracer.record("pe.kernel:memcpy", 1.0, 2.0, desc_id=3, trace_id="d3")
+    tracer.wait_span("umwait", 1.0, 2.0, busy_s=0.5, free_s=0.5, completions=1)
+    gc.collect()
+    assert len(tracer._spans) == 2
+    assert not any(gc.is_tracked(sp) for sp in tracer._spans)
+    wait = tracer.host_spans("wait.")[0]
+    assert wait.attrs == {"busy_s": 0.5, "free_s": 0.5, "completions": 1}
+
+
+def test_concurrent_spans_are_all_kept_or_counted():
+    """Threads recording into one tracer while its ring rotates: every span
+    is either retained or counted in ``dropped``, none lost."""
+    import sys
+    import threading
+
+    tracer = Tracer(TraceConfig(capacity=16))  # host-span ring of 128
+    n_threads, per = 8, 500
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        def work(k):
+            for i in range(per):
+                tracer.record(f"pe.kernel:t{k}", 0.0, 1.0, desc_id=i)
+
+        threads = [threading.Thread(target=work, args=(k,)) for k in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads)
+    kept = len(tracer.host_spans())
+    assert kept == 128
+    assert kept + tracer.counters_snapshot()["dropped"] == n_threads * per

@@ -192,7 +192,8 @@ def render_frame(sampler: Sampler, device, numa: bool, frame: int) -> str:
         f"queue_full={row.get('device.queue_full', 0):.0f}"
     )
     if any(k.startswith("trace.") for k in row):
-        parts = [f"sampled=+{row.get('trace.sampled', 0):.0f}"]
+        parts = [f"sampled=+{row.get('trace.sampled', 0):.0f}",
+                 f"gc={row.get('trace.gc_pause_ms', 0):.1f}ms"]
         for phase in PHASES:
             occ = row.get(f"trace.phase.{phase}.occupancy")
             if occ:

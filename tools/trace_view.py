@@ -129,9 +129,9 @@ def host_free_cross_check(tracer) -> dict:
     flags an instrumentation bug."""
     spans_frac = host_free_fraction(tracer)
     busy = free = 0.0
-    for w in tracer.wait_spans():
-        busy += w.busy_s
-        free += w.free_s
+    for w in tracer.host_spans("wait."):
+        busy += w.attrs["busy_s"]
+        free += w.attrs["free_s"]
     total = busy + free
     waitstats_frac = (free / total) if total > 0 else None
     delta = (abs(spans_frac - waitstats_frac)
