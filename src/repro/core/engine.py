@@ -259,6 +259,8 @@ class StreamEngine:
             # one ``fused_batches`` per doorbell — pcm_repro derives its
             # submits/s and fused-batch-ratio columns from these
             "submitted": 0, "fused_batches": 0, "fused_descs": 0,
+            # batch_copy kernel calls by the path ops.batch_copy_path chose
+            "batch_copy_dma": 0, "batch_copy_vector": 0,
         }
         self._counters_lock = _lockcheck.checked_lock("engine.counters")
         # deferred submissions waiting on dependency fences:
@@ -311,6 +313,10 @@ class StreamEngine:
             if fused:
                 c["fused_batches"] += 1
                 c["fused_descs"] += n
+
+    def _count_batch_copy(self, pool) -> None:
+        with self._counters_lock:
+            self.counters["batch_copy_" + ops.batch_copy_path(pool)] += 1
 
     def counters_snapshot(self) -> Dict[str, float]:
         """Point-in-time copy of the monotonic counters (delta-sampling
@@ -703,6 +709,7 @@ class StreamEngine:
         elif d.op == OpType.DIF_STRIP:
             out = dif_ops.dif_strip(d.src)
         elif d.op == OpType.BATCH_COPY:
+            self._count_batch_copy(d.src)
             out = ops.batch_copy(d.src, d.dst_pool, d.src_idx, d.dst_idx, interpret=it)
         elif d.op == OpType.COPY_CRC:
             out = ops.copy_crc(d.src, interpret=it)
@@ -736,6 +743,7 @@ class StreamEngine:
             idx = jnp.arange(len(descs), dtype=jnp.int32)
             if tr is None:
                 pool = jnp.stack([d.src for d in descs])
+                self._count_batch_copy(pool)
                 out = list(ops.batch_copy(pool, jnp.zeros_like(pool), idx, idx,
                                           interpret=self.interpret))
             else:
@@ -748,6 +756,7 @@ class StreamEngine:
                 t = tr.record_span("pe.kernel:batch_copy", t)
                 out = list(out)
                 tr.record_span("pe.unstack", t)
+                self._count_batch_copy(pool)
             nbytes = b.nbytes
             _, _, hops = self._locality(b)
             t = self.model.op_time(descs[0].nbytes,

@@ -320,14 +320,30 @@ def delta_apply(ref: jax.Array, offsets: jax.Array, data: jax.Array, *,
 
 
 # --------------------------------------------------------------------------- batch copy (paged)
+def batch_copy_path(pool) -> str:
+    """Which kernel ``batch_copy`` runs for ``pool`` ([n_pages, *page]).
+
+    ``"dma"``: a page of 2 or more dims is whole HBM tiles, so it is sliced
+    off the leading dim and moved HBM -> HBM in the pool's own layout.
+    ``"vector"``: a 1-D page is one row of a tiled 2-D array, which a DMA
+    cannot slice on its own, so pages go through the u32 word view."""
+    return "dma" if len(pool.shape) >= 3 else "vector"
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",), donate_argnums=(1,))
 def batch_copy(src_pool: jax.Array, dst_pool: jax.Array, src_idx: jax.Array,
                dst_idx: jax.Array, *, interpret: Optional[bool] = None) -> jax.Array:
-    """Batch-descriptor page copy: dst_pool[dst_idx[i]] = src_pool[src_idx[i]].
+    """Batch-descriptor page copy: dst_pool[dst_idx[i]] = src_pool[src_idx[i]],
+    a later descriptor winning where two write one page.
 
-    Pools are [n_pages, ...page_shape...] of any dtype; pages are bit-cast to
-    word tiles internally."""
+    Pools are [n_pages, ...page_shape...] of any dtype.  The kernel follows
+    ``batch_copy_path``: page DMAs on the pools as they are, or pages
+    bit-cast to word tiles."""
     interpret = _interpret_default() if interpret is None else interpret
+    src_idx = src_idx.astype(jnp.int32)
+    dst_idx = dst_idx.astype(jnp.int32)
+    if batch_copy_path(src_pool) == "dma":
+        return _bc.batch_copy_dma(src_pool, dst_pool, src_idx, dst_idx, interpret=interpret)
     P = src_pool.shape[0]
     Q = dst_pool.shape[0]
     page_shape = src_pool.shape[1:]
@@ -342,7 +358,6 @@ def batch_copy(src_pool: jax.Array, dst_pool: jax.Array, src_idx: jax.Array,
         return flat.reshape(k, rows, LANES)
 
     out = _bc.batch_copy_pages(pool_words(src_pool, P), pool_words(dst_pool, Q),
-                               src_idx.astype(jnp.int32), dst_idx.astype(jnp.int32),
-                               interpret=interpret)
+                               src_idx, dst_idx, interpret=interpret)
     pages = out.reshape(Q, -1)[:, :page_words]
     return jax.vmap(lambda w: from_words(w, page_shape, dst_pool.dtype))(pages)

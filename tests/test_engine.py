@@ -68,6 +68,22 @@ def test_batch_fusion_equals_individual(rng):
         assert np.allclose(np.asarray(o), np.asarray(x))
 
 
+def test_batch_copy_path_counters(rng):
+    """A 3-D page pool's batch copy counts on the DMA path; a fused burst of
+    1-D packets (a 2-D pool) on the vector path."""
+    s = make_device()
+    eng = s.engines[0]
+    pool = jnp.asarray(rng.normal(size=(4, 8, 128)), jnp.float32)
+    idx = jnp.asarray([1, 3], jnp.int32)
+    out = s.batch_copy_async(pool, jnp.zeros_like(pool), idx, idx).result()
+    assert (np.asarray(out)[[1, 3]] == np.asarray(pool)[[1, 3]]).all()
+    assert (eng.counters["batch_copy_dma"], eng.counters["batch_copy_vector"]) == (1, 0)
+    packets = [jnp.asarray(rng.integers(0, 255, 64), jnp.uint8) for _ in range(4)]
+    outs = s.batch_async([WorkDescriptor(op=OpType.MEMCPY, src=p) for p in packets]).result()
+    assert all((np.asarray(o) == np.asarray(p)).all() for o, p in zip(outs, packets))
+    assert (eng.counters["batch_copy_dma"], eng.counters["batch_copy_vector"]) == (1, 1)
+
+
 def test_mixed_batch(rng):
     s = make_device()
     x = jnp.asarray(rng.integers(0, 2**31, 1024), jnp.uint32)

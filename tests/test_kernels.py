@@ -110,18 +110,65 @@ def test_delta_overflow_flag(rng):
     assert bool(ovf) and int(count) == 256
 
 
-def test_batch_copy_matches_ref(rng):
-    P, page = 12, (8, 128)
-    src_pool = jnp.asarray(rng.normal(size=(P,) + page), jnp.float32)
-    dst_pool = jnp.asarray(rng.normal(size=(P,) + page), jnp.float32)
-    src_idx = jnp.asarray([0, 3, 3, 11], jnp.int32)
-    dst_idx = jnp.asarray([5, 2, 7, 0], jnp.int32)
+# (dtype, P, page, Q, src_idx, dst_idx): pools [P, *page] -> [Q, *page]
+BATCH_COPY_CASES = {
+    "f32": (jnp.float32, 12, (8, 128), 12, [0, 3, 3, 11], [5, 2, 7, 0]),
+    "bf16-P<Q": (jnp.bfloat16, 5, (16, 256), 9, [4, 0, 2], [8, 1, 3]),
+    "f32-P>Q": (jnp.float32, 20, (8, 128), 7, [19, 0, 7, 13], [6, 2, 0, 5]),
+    "n1": (jnp.bfloat16, 4, (16, 128), 6, [3], [5]),
+    # a later descriptor wins where two write one page, in flight or not
+    "dup-dst": (jnp.float32, 6, (8, 128), 6, [0, 1, 2, 3, 4, 5, 0, 1, 2, 3, 4, 5],
+                [1, 1, 2, 1, 3, 3, 4, 5, 4, 0, 2, 2]),
+    "dup-src-deep": (jnp.bfloat16, 8, (16, 128), 40, [i % 3 for i in range(30)],
+                     list(range(30))),
+    "4d-page": (jnp.float32, 6, (2, 8, 128), 6, [5, 4, 5], [0, 0, 3]),
+    "vector-2d": (jnp.uint8, 32, (64,), 32, [7, 0, 31, 7], [1, 1, 30, 2]),
+}
+
+
+@pytest.mark.parametrize("case", list(BATCH_COPY_CASES))
+def test_batch_copy_matches_ref(rng, case):
+    dtype, P, page, Q, src_idx, dst_idx = BATCH_COPY_CASES[case]
+    src_pool = _rand(rng, (P,) + page, dtype)
+    dst_pool = _rand(rng, (Q,) + page, dtype)
+    src_idx = jnp.asarray(src_idx, jnp.int32)
+    dst_idx = jnp.asarray(dst_idx, jnp.int32)
     want = ref.batch_copy_ref(src_pool, dst_pool, src_idx, dst_idx)
     got = ops.batch_copy(src_pool, jnp.array(dst_pool), src_idx, dst_idx)
-    assert (np.asarray(got) == np.asarray(want)).all()
+    assert got.shape == want.shape and got.dtype == want.dtype
+    assert (np.asarray(got).view(np.uint8) == np.asarray(want).view(np.uint8)).all()
     # untouched pages preserved
-    untouched = sorted(set(range(P)) - set(np.asarray(dst_idx)))
+    untouched = sorted(set(range(Q)) - set(np.asarray(dst_idx)))
     assert (np.asarray(got)[untouched] == np.asarray(dst_pool)[untouched]).all()
+
+
+def test_batch_copy_path():
+    assert ops.batch_copy_path(jnp.zeros((32, 64), jnp.uint8)) == "vector"
+    assert ops.batch_copy_path(jnp.zeros((4, 16, 64), jnp.bfloat16)) == "dma"
+    assert ops.batch_copy_path(jnp.zeros((4, 2, 8, 128), jnp.float32)) == "dma"
+
+
+@pytest.mark.parametrize("detect_races", [False, True])
+def test_batch_copy_dma_in_flight(rng, detect_races):
+    """The DMA kernel under the TPU interpreter's own semaphores and DMA
+    queue: copies run when waited on, and with the race detector on no two
+    copies in flight write one page.  Destinations repeat within and across
+    the in-flight window."""
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call as ipc
+    from jax.experimental.pallas import tpu as pltpu
+
+    from repro.kernels import batch_copy as bc
+
+    n = 3 * bc.DEPTH + 1
+    src_pool = _rand(rng, (n, 8, 128), jnp.float32)
+    dst_pool = _rand(rng, (7, 8, 128), jnp.float32)
+    src_idx = jnp.arange(n, dtype=jnp.int32)
+    dst_idx = jnp.asarray(rng.integers(0, 7, n), jnp.int32)
+    params = pltpu.InterpretParams(detect_races=detect_races, vector_clock_size=256)
+    got = bc.batch_copy_dma(src_pool, jnp.array(dst_pool), src_idx, dst_idx, interpret=params)
+    want = ref.batch_copy_ref(src_pool, dst_pool, src_idx, dst_idx)
+    assert (np.asarray(got) == np.asarray(want)).all()
+    assert not (detect_races and ipc.races.races_found)
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])

@@ -105,3 +105,17 @@ def test_kernel_compiles_for_v5e(one_chip, case):
             for shape, dtype in arg_specs]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_batch_copy_kv_swap_moves_pages_in_place(one_chip):
+    """The KV cell's largest swap (bf16[16384, 16, 4096] pools, 4,592 pages
+    of 128 KiB) compiles to page DMAs on the pools as they are: no
+    temporary of the size of a pool, as a relayout of both pools would
+    need, and the output aliased to the donated destination."""
+    pool = jax.ShapeDtypeStruct((16384, 16, 4096), jnp.bfloat16, sharding=one_chip)
+    idx = jax.ShapeDtypeStruct((4592,), jnp.int32, sharding=one_chip)
+    compiled = ops.batch_copy.lower(pool, pool, idx, idx, interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < MiB
+    assert mem.alias_size_in_bytes == 16384 * 16 * 4096 * 2
