@@ -3,14 +3,14 @@ chip attached, and print each one's ``memory_analysis()``.
 
     JAX_PLATFORMS=cpu python bench/rehearse.py
 
-What the TPU compiler refuses here costs no chip time.  The KV pools are
-sized from what this prints: the swap program's arguments plus its
-temporaries have to fit the chip's HBM beside what else the process holds.
+What the TPU compiler refuses here costs no chip time.  Each configuration
+of BENCHMARK.json is handed, with the traffic mixes of its cells, to its
+system's ``rehearse`` in ``bench/cases/<system>.py``, which names the
+programs and takes their sizes from those files.
 """
 from __future__ import annotations
 
 import json
-import math
 import os
 import sys
 from pathlib import Path
@@ -26,13 +26,10 @@ GIB = 1 << 30
 
 def main() -> int:
     import jax
-    import jax.numpy as jnp
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from bench import spec, traffic
-    from bench.systems import kvswap
-    from repro.kernels import ops
+    from bench import cases, spec, traffic
 
     jax.config.update("jax_enable_compilation_cache", False)
     chip = SingleDeviceSharding(
@@ -49,32 +46,10 @@ def main() -> int:
                           "temp_gib": m.temp_size_in_bytes / GIB}), flush=True)
 
     bm = spec.load_benchmark()
-    for size in traffic.load("imix-steady")["sizes"]["values"]:
-        report(f"memcpy u8[{size}]", ops.memcpy, shape((size,), jnp.uint8), interpret=False)
-    burst = traffic.load("64b-closed")["burst"]
-    idx = shape((burst,), jnp.int32)
-    report(f"batch_copy u8[{burst},64] (fused 64 B burst)", ops.batch_copy,
-           shape((burst, 64), jnp.uint8), shape((burst, 64), jnp.uint8), idx, idx, interpret=False)
-    cfg = spec.config(bm, "kv-offload-dsmoe16b")
-    page = (cfg["block_size"], 2 * cfg["num_key_value_heads"] * cfg["head_dim"])
-    page_bytes = math.prod(page) * 2
-    dev = int(cfg["device_pool_gib"] * GIB) // page_bytes
-    host = int(cfg["swap_space_gib"] * GIB) // page_bytes
-    mix = traffic.load("sessions-long")
-    counts = sorted({cfg["num_hidden_layers"] * -(-int(t) // cfg["block_size"])
-                     for t in traffic.session_tokens(mix, 0)})
-    for n in (counts[0], counts[-1]):
-        for name, (p, q) in (("swap_out", (dev, host)), ("swap_in", (host, dev))):
-            report(f"batch_copy {name} bf16[{p}|{q},{page[0]},{page[1]}] x {n} pages",
-                   ops.batch_copy, shape((p,) + page, jnp.bfloat16),
-                   shape((q,) + page, jnp.bfloat16), shape((n,), jnp.int32),
-                   shape((n,), jnp.int32), interpret=False)
-    fill = jax.jit(kvswap._fill, static_argnums=(3,))
-    report(f"kv pool fill bf16[{dev},{page[0]},{page[1]}]", fill, shape((), jnp.uint32),
-           shape((dev,), jnp.int32), shape((dev,), jnp.int32), page)
-    report("kv check block", jax.jit(kvswap._mismatched_pages),
-           shape((dev,) + page, jnp.bfloat16), *[shape((kvswap.CHECK_BLOCK,), jnp.int32)] * 3,
-           shape((kvswap.CHECK_BLOCK,), jnp.bool_), shape((), jnp.uint32))
+    for c in bm["configs"]:
+        cfg = spec.config(bm, c["name"])
+        mixes = [traffic.load(w["traffic"]) for w in bm["workloads"] if w["config"] == c["name"]]
+        cases.of(cfg).rehearse(cfg, mixes, report, shape)
     return 0
 
 

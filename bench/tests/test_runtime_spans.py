@@ -174,10 +174,9 @@ def test_traced_runs_report_the_programs_spans_and_drop_none(cell):
     """A small traced run of each cell on the CPU, through bench/run.py's
     ``run_cell`` and through ``explain_cell``: every span metric the cell
     lists is read, and the tracer's rings dropped nothing."""
-    from bench import run
-    from bench.tests.test_faults import small
+    from bench import cases, run
 
-    cfg, mix = small(cell)
+    _, cfg, mix = cases.small_cell(cell)
     bm = spec.load_benchmark()
     want = {m["name"] for m in spec.metrics(bm, cell, "per_layer")
             if m["source"] == "program_span"}
@@ -196,10 +195,10 @@ def test_traced_runs_report_the_programs_spans_and_drop_none(cell):
 def test_explain_runs_without_the_profiler(mode):
     """The modes that split what tracing costs: the tracer alone reports
     the span metrics; untraced, the end-to-end metrics and the collections."""
-    from bench.tests.test_faults import small
+    from bench import cases
 
     cell = "vhost-64b.closed"
-    cfg, mix = small(cell)
+    _, cfg, mix = cases.small_cell(cell)
     bm = spec.load_benchmark()
     got = explain.explain_cell(cell, 2**31 + 12, 0.4, mode=mode, cfg=cfg, mix=mix)
     assert got["mode"] == mode and "idle_gaps" not in got

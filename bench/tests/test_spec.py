@@ -50,10 +50,15 @@ def test_config_file_and_system_are_found(cfg):
 def test_cell_finds_traffic_and_reports_enough(cell):
     mix = traffic.load(cell["traffic"])
     assert mix["loop"] in ("open", "closed")
-    assert cell["chips"] == 1
+    assert cell["chips"] in (1, 4)
     e2e = [m["name"] for m in spec.metrics(BM, cell["name"], "end_to_end")]
     assert "setup_s" in e2e and len(e2e) >= 2
     assert spec.metrics(BM, cell["name"], "per_layer")
+
+
+def test_at_most_half_the_cells_take_four_chips():
+    four = [w["name"] for w in BM["workloads"] if w["chips"] == 4]
+    assert len(four) <= max(1, len(CELLS) // 2), four
 
 
 @pytest.mark.parametrize("metric", METRICS, ids=lambda m: m["name"])
