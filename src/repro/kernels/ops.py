@@ -213,10 +213,24 @@ def dualcast(x: jax.Array, *, interpret: Optional[bool] = None):
 
 
 # --------------------------------------------------------------------------- crc32
+def _crc_in_place(x: jax.Array) -> bool:
+    """Whether the CRC kernel reads ``x`` where it lies: 4-byte items whose
+    rows (all but the last dim) come in whole (8, L) TPU tiles, so that
+    [R, L] -> [1, R/8, 8, L] keeps the layout, with a tile that fits a
+    grid step."""
+    return (x.dtype.itemsize == 4 and x.ndim >= 2 and x.shape[-2] % _crc.TILE_ROWS == 0
+            and 4 * _crc.TILE_ROWS * x.shape[-1] <= _crc.BLOCK_BYTES)
+
+
 def _crc_tiles(x: jax.Array) -> Tuple[jax.Array, int]:
-    """[1, T, 8, 128] int32 tiles of ``x``'s bytes, zero-padded at the
-    FRONT to whole tiles (leading zeros leave a zero-init CRC register
-    unchanged), and the front pad in bytes."""
+    """[1, T, S, L] tiles of ``x``'s bytes in order, and the front pad in
+    bytes.  An array the kernel reads in place (``_crc_in_place``) is split
+    into tiles of 8 whole rows, in its own dtype and with no copy; any
+    other is flattened into int32 (8, 128) tiles, zero-padded at the FRONT
+    to whole tiles (leading zeros leave a zero-init CRC register
+    unchanged)."""
+    if _crc_in_place(x):
+        return x.reshape(1, -1, _crc.TILE_ROWS, x.shape[-1]), 0
     nbytes = _nbytes(x)
     tile_bytes = 4 * _crc.TILE_WORDS
     pad = (-nbytes) % tile_bytes
@@ -238,7 +252,7 @@ def crc32(x: jax.Array, *, interpret: Optional[bool] = None) -> jax.Array:
     if x.size == 0:
         return jnp.uint32(0)
     tiles, _ = _crc_tiles(x)
-    q = _crc.crc_streams(tiles, gap=_crc.TILE_WORDS, interpret=interpret)
+    q = _crc.crc_streams(tiles, interpret=interpret)
     return _crc_value(q, _nbytes(x))
 
 
@@ -254,13 +268,13 @@ def copy_crc(x: jax.Array, *, interpret: Optional[bool] = None):
     if x.size == 0:
         return x, jnp.uint32(0)
     tiles, pad = _crc_tiles(x)
-    q, dst = _crc.crc_streams(tiles, gap=_crc.TILE_WORDS, copy=True,
-                              interpret=interpret)
-    flat = _u32(dst).reshape(-1)
-    if pad % 4:  # byte-granular x (itemsize 1 or 2) after a partial word
-        copy = _split(flat, x.dtype)[pad // x.dtype.itemsize:].reshape(x.shape)
+    q, dst = _crc.crc_streams(tiles, copy=True, interpret=interpret)
+    if _crc_in_place(x):  # back to the leaf's own shape, no relayout
+        copy = dst.reshape(x.shape)
+    elif pad % 4:  # byte-granular x (itemsize 1 or 2) after a partial word
+        copy = _split(_u32(dst).reshape(-1), x.dtype)[pad // x.dtype.itemsize:].reshape(x.shape)
     else:
-        copy = from_words(flat[pad // 4:], x.shape, x.dtype)
+        copy = from_words(_u32(dst).reshape(-1)[pad // 4:], x.shape, x.dtype)
     return copy, _crc_value(q, _nbytes(x))
 
 

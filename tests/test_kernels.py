@@ -87,6 +87,24 @@ def test_crc32_over_dtypes(rng):
     assert got == want
 
 
+@pytest.mark.parametrize("shape,dtype", [((64, 2048), np.float32), ((16, 1408), np.float32),
+                                         ((24, 2816), np.int32), ((16, 11264), np.float32)])
+def test_crc_of_a_leaf_read_in_place(rng, shape, dtype):
+    """2-D 4-byte leaves of whole 8-row tiles, at the checkpoint cell's row
+    widths (several tiles a grid step, lane chunks of 512 and of 128): the
+    kernel reads them in their own layout, and the copy and both CRCs are
+    bit-exact with zlib."""
+    a = (rng.normal(size=shape).astype(dtype) if dtype == np.float32  # no NaN payloads
+         else rng.integers(-(2**31), 2**31, size=shape).astype(dtype))
+    x = jnp.asarray(a)
+    assert ops._crc_in_place(x)
+    want = zlib.crc32(a.tobytes()) & 0xFFFFFFFF
+    copy, crc = ops.copy_crc(x)
+    assert copy.dtype == x.dtype and copy.shape == x.shape
+    assert np.asarray(copy).tobytes() == a.tobytes()
+    assert int(crc) == want and int(ops.crc32(x)) == want
+
+
 @pytest.mark.parametrize("n,k", [(512, 10), (4096, 100), (1024, 0)])
 def test_delta_roundtrip(rng, n, k):
     base = jnp.asarray(rng.integers(0, 2**31, n), jnp.uint32)

@@ -119,3 +119,17 @@ def test_batch_copy_kv_swap_moves_pages_in_place(one_chip):
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < MiB
     assert mem.alias_size_in_bytes == 16384 * 16 * 4096 * 2
+
+
+@pytest.mark.parametrize("op", ["copy_crc", "crc32"])
+@pytest.mark.parametrize("shape", [(20480, 2048), (2048, 11264), (1408, 2048)],
+                         ids=["embedding", "dense_down", "expert"])
+def test_crc_reads_a_checkpoint_leaf_in_place(one_chip, op, shape):
+    """Float32 leaves of the checkpoint cell (Moonlight-16B-A3B's embedding
+    slice, dense down projection and one expert's matrix) are read where
+    they lie: no temporary of a leaf's size, as a relayout into (8, 128)
+    word tiles would need; only the stream fold's few hundred KiB."""
+    leaf = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    compiled = getattr(ops, op).lower(leaf, interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * MiB
